@@ -299,6 +299,10 @@ class ResidentSet:
         plan = self.spec.plan(n_words)
         return {b: n_bits * n for (_d, b), n in plan.bank_counts(1).items()}
 
+    def _load_tiles(self, n_words: int) -> int:
+        """Load accesses one pin of n_words charges: one per tile."""
+        return self.spec.plan(n_words).n_tiles
+
     def fits(self, rows_by_bank: Dict[int, int]) -> bool:
         occ = self.rows_per_bank()
         budget = self.spec.rows - self.reserve_rows
@@ -374,7 +378,7 @@ class ResidentSet:
         self._entries[key] = entry
         self.pins += 1
         _STATS["resident_pins"] += 1
-        n_tiles = self.spec.plan(pack.n_words).n_tiles
+        n_tiles = self._load_tiles(pack.n_words)
         LEDGER.charge_load(pack.n_bits, pack.n_words, n_tiles=n_tiles)
         if n_ecc:
             LEDGER.charge_ecc(n_ecc, pack.n_words, n_tiles=n_tiles)
@@ -531,6 +535,23 @@ class ResidentSet:
                 "resident_rows": self.resident_rows}
 
 
+class UnbankedResidentSet(ResidentSet):
+    """The resident region of the unbanked array that `spec=None` lowering
+    computes on. That array has no bank geometry: a streamed entry pack
+    there costs one load access whatever its size, and nothing bounds its
+    rows. Pins are modelled the same way — one load access each and no row
+    budget — so residency is compared with streaming on one geometry."""
+
+    def _rows_for(self, n_bits: int, n_words: int) -> Dict[int, int]:
+        return {0: n_bits}
+
+    def _load_tiles(self, n_words: int) -> int:
+        return 1
+
+    def _make_room(self, key: Tuple, rows_by_bank: Dict[int, int]) -> None:
+        return None
+
+
 #: every live ResidentSet (weak: test-local sets vanish with their tests)
 _ALL_SETS: "weakref.WeakSet[ResidentSet]" = weakref.WeakSet()
 
@@ -549,8 +570,9 @@ def _reset_stats() -> None:
 _reset_stats()
 
 #: process-wide resident set per geometry (the one `resident_rows_for`
-#: consults and the serving stack shares between weight pins and KV pages)
-_RESIDENT_SETS: Dict[ArraySpec, ResidentSet] = {}
+#: consults and the serving stack shares between weight pins and KV pages);
+#: the key None holds the unbanked array's set (`lowering_resident_set`)
+_RESIDENT_SETS: Dict[Optional[ArraySpec], ResidentSet] = {}
 
 #: whether registry ResidentSets are created ECC-protected (serving turns
 #: this on before building its lowered state; default off keeps the
@@ -611,6 +633,18 @@ def resident_set(spec: Optional[ArraySpec] = None) -> ResidentSet:
     if rs is None:
         rs = _RESIDENT_SETS[spec] = ResidentSet(
             spec, reserve_rows=spec.rows // 4, ecc=_DEFAULT_ECC)
+    return rs
+
+
+def lowering_resident_set(spec: Optional[ArraySpec]) -> ResidentSet:
+    """The registry set a lowering on `spec` pins into: `resident_set(spec)`
+    when banked, the unbanked array's set (`UnbankedResidentSet`) when
+    `spec` is None."""
+    if spec is not None:
+        return resident_set(spec)
+    rs = _RESIDENT_SETS.get(None)
+    if rs is None:
+        rs = _RESIDENT_SETS[None] = UnbankedResidentSet(ecc=_DEFAULT_ECC)
     return rs
 
 

@@ -151,7 +151,10 @@ def _jnp_boolean_backend(a_planes, b_planes, ops):
         results["gt"] = (~s_ext & nz)[None, :]
     for fn, planes in out.items():
         results[fn] = jnp.stack(planes)
-    return tuple(results[op] for op in ops)
+    # one access per fusion: XLA would otherwise fuse a whole unrolled
+    # schedule of plane ops into one loop, which LLVM takes minutes to
+    # optimize on the CPU
+    return jax.lax.optimization_barrier(tuple(results[op] for op in ops))
 
 
 # ---------------------------------------------------------------------------

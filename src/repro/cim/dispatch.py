@@ -41,18 +41,6 @@ from .backends import Backend, get_backend
 from .planepack import PlanePack
 
 
-def _shard_map(body, mesh, in_specs, out_specs):
-    """Version-portable shard_map (jax>=0.6: jax.shard_map/check_vma;
-    older: jax.experimental.shard_map/check_rep)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
 # ---------------------------------------------------------------------------
 # compiled-schedule cache (bounded LRU)
 # ---------------------------------------------------------------------------
@@ -261,8 +249,8 @@ def _tiled_body(ops: Tuple[str, ...], bk: Backend, mesh, axis):
     from jax.sharding import PartitionSpec as P
 
     spec3 = P(axis, None, None)
-    return _shard_map(tiled, mesh, in_specs=(spec3, spec3),
-                      out_specs=tuple(spec3 for _ in ops))
+    return jax.shard_map(tiled, mesh=mesh, in_specs=(spec3, spec3),
+                         out_specs=tuple(spec3 for _ in ops), check_vma=False)
 
 
 # ---------------------------------------------------------------------------

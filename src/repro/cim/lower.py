@@ -18,9 +18,9 @@
      replay from the trace-time PlannedCharges record. Region inputs that
      are dead after the region (intermediates, never the caller's arrays)
      are donated to the program on accelerator platforms, letting XLA reuse
-     their buffers for the accumulator chain.
+     their buffers for the region's outputs of the same shape and dtype.
   3. Everything else executes on the host, eqn by eqn, exactly as
-     `jax.core.eval_jaxpr` would.
+     evaluating the jaxpr would.
 
 The hybrid callable is bit-exact with the original function: every CiM op
 result is truncated/extended to its eqn's output dtype in the packed domain
@@ -43,7 +43,7 @@ feeding a contraction is unpacked first. Elementwise chains never repack.
 from __future__ import annotations
 
 import dataclasses
-from collections import OrderedDict
+from collections import Counter, OrderedDict
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -57,7 +57,8 @@ from . import trace as trace_mod
 from .array import ArraySpec
 from .opset import CimOpError
 from .planepack import PlanePack
-from .trace import CMP_PRIMS, ConstVal, TracedOp, aval_of, dtype_bits, dtype_signed
+from .trace import (CMP_PRIMS, ConstVal, DropVar, Literal, TracedOp, Var,
+                    aval_of, dtype_bits, dtype_signed)
 
 _FULL32 = np.uint32(0xFFFFFFFF)
 
@@ -200,12 +201,12 @@ def _region_in_atoms(region: Region) -> Tuple[Any, ...]:
     """External operands of a region, in first-use order: Vars produced
     outside it plus ConstVals (deduped; Literals stay baked in)."""
     produced = {v for op in region.ops for v in op.outvars
-                if not isinstance(v, jax.core.DropVar)}
+                if not isinstance(v, DropVar)}
     atoms: List[Any] = []
     seen: set = set()
     for op in region.ops:
         for a in op.invars:
-            if isinstance(a, jax.core.Var):
+            if isinstance(a, Var):
                 if a not in produced and a not in seen:
                     seen.add(a)
                     atoms.append(a)
@@ -218,6 +219,12 @@ def _region_in_atoms(region: Region) -> Tuple[Any, ...]:
 
 #: shared cache-key signature discipline (ONE definition, see macro.aval_sig)
 _aval_sig = macro.aval_sig
+
+
+def _buffer_sig(v) -> Tuple:
+    """What decides whether XLA can reuse one buffer for another."""
+    aval = aval_of(v)
+    return tuple(aval.shape), str(aval.dtype)
 
 
 def _region_key(region: Region) -> Tuple:
@@ -239,12 +246,12 @@ def _region_key(region: Region) -> Tuple:
     for op in region.ops:
         ins = []
         for a in op.invars:
-            if isinstance(a, jax.core.Literal):
+            if isinstance(a, Literal):
                 ins.append(("lit", np.asarray(a.val).tobytes(),
                             _aval_sig(a.aval)))
             else:
                 ins.append(("v", ref(a), _aval_sig(aval_of(a))))
-        outs = tuple(("drop",) if isinstance(v, jax.core.DropVar)
+        outs = tuple(("drop",) if isinstance(v, DropVar)
                      else ("v", ref(v), _aval_sig(v.aval))
                      for v in op.outvars)
         parts.append((op.name, tuple(ins), outs))
@@ -306,7 +313,7 @@ def _classify_resident(region: Region, ai: int, atom) -> \
         if len(cons) != 1 \
                 or op.name not in ("convert_element_type", "reshape") \
                 or op.invars[0] is not frontier \
-                or isinstance(op.outvars[0], jax.core.DropVar) \
+                or isinstance(op.outvars[0], DropVar) \
                 or op.outvars[0] in region.unpack_vars:
             rhs_only = False
             break
@@ -345,8 +352,13 @@ def _classify_resident(region: Region, ai: int, atom) -> \
                         signed=dtype_signed(aval.dtype), n_words=n_words)
 
 
+#: a resident atom's plain entry pack, built as one program (see
+#: macro.matmul_rhs_pack)
+_pack_program = jax.jit(PlanePack.pack, static_argnames=("n_bits", "signed"))
+
+
 def _read_host(env: Dict[Any, Any], atom):
-    if isinstance(atom, jax.core.Literal):
+    if isinstance(atom, Literal):
         return jnp.asarray(atom.val, dtype=atom.aval.dtype)
     if isinstance(atom, ConstVal):
         return atom.val
@@ -378,7 +390,7 @@ class LoweredComputation:
         # once here for the construction-time residency budget planning
         self._registry_rs = resident_set is None
         if resident_set is None and self.resident_leaf_idx:
-            resident_set = array_mod.resident_set(spec)
+            resident_set = array_mod.lowering_resident_set(spec)
         self.resident_set = resident_set
         # the cost model decides, per eligible eqn, whether lowering pays
         # under `policy` (repro.cim.cost); demoted eqns run on host
@@ -430,7 +442,7 @@ class LoweredComputation:
 
         # which region outputs must materialize for host consumers / outputs
         out_roots = {v for v in self.trace.closed.jaxpr.outvars
-                     if isinstance(v, jax.core.Var)}
+                     if isinstance(v, Var)}
         consumed_after: List[set] = [set() for _ in items]
         acc: set = set(out_roots)
         for i in range(len(items) - 1, -1, -1):
@@ -439,20 +451,20 @@ class LoweredComputation:
             ops = payload.ops if kind == "region" else [payload]
             for op in ops:
                 acc.update(v for v in op.invars
-                           if isinstance(v, jax.core.Var))
+                           if isinstance(v, Var))
         caller_owned = set(self.trace.closed.jaxpr.invars) \
             | set(self.trace.closed.jaxpr.constvars)
-        # an _alias eqn (pjit-inlining passthrough) binds its outvar to the
+        # an _alias eqn (jit-inlining passthrough) binds its outvar to the
         # SAME jax.Array as its source — caller arguments and still-live
         # vars included — so any var touching an alias is unsafe to donate
         alias_tainted: set = set()
         for op in self.trace.ops:
             if op.name == "_alias":
                 alias_tainted.update(
-                    v for v in op.invars if isinstance(v, jax.core.Var))
+                    v for v in op.invars if isinstance(v, Var))
                 alias_tainted.update(
                     v for v in op.outvars
-                    if not isinstance(v, jax.core.DropVar))
+                    if not isinstance(v, DropVar))
         for i, (kind, payload) in enumerate(items):
             if kind == "region":
                 payload.unpack_vars = tuple(
@@ -461,13 +473,19 @@ class LoweredComputation:
                 payload.in_atoms = _region_in_atoms(payload)
                 # inputs dead after this region (and neither the caller's
                 # own buffers nor alias-shared ones) may be donated to the
-                # compiled region program
-                payload.donatable = tuple(
-                    j for j, a in enumerate(payload.in_atoms)
-                    if isinstance(a, jax.core.Var)
-                    and a not in caller_owned
-                    and a not in alias_tainted
-                    and a not in consumed_after[i])
+                # compiled region program; XLA reuses a donated buffer only
+                # for an output of its shape and dtype, so donate no more
+                # than the outputs can take
+                free = Counter(_buffer_sig(v) for v in payload.unpack_vars)
+                donatable = []
+                for j, a in enumerate(payload.in_atoms):
+                    if (isinstance(a, Var) and a not in caller_owned
+                            and a not in alias_tainted
+                            and a not in consumed_after[i]
+                            and free[_buffer_sig(a)] > 0):
+                        free[_buffer_sig(a)] -= 1
+                        donatable.append(j)
+                payload.donatable = tuple(donatable)
                 payload.key = _region_key(payload)
 
     # -- residency planning -------------------------------------------------
@@ -490,15 +508,15 @@ class LoweredComputation:
         jaxpr = self.trace.closed.jaxpr
         derived = {jaxpr.invars[i] for i in self.resident_leaf_idx}
         for op in self.trace.ops:
-            vars_in = [a for a in op.invars if isinstance(a, jax.core.Var)]
+            vars_in = [a for a in op.invars if isinstance(a, Var)]
             if all(v in derived for v in vars_in):
                 derived.update(v for v in op.outvars
-                               if not isinstance(v, jax.core.DropVar))
+                               if not isinstance(v, DropVar))
         budget = rs.spec.rows - rs.reserve_rows
         for region in self.regions:
             resident: List[ResidentAtom] = []
             for ai, atom in enumerate(region.in_atoms):
-                if not isinstance(atom, jax.core.Var) or atom not in derived:
+                if not isinstance(atom, Var) or atom not in derived:
                     continue
                 ra = _classify_resident(region, ai, atom)
                 if ra is None:
@@ -517,7 +535,7 @@ class LoweredComputation:
                     j for j in region.donatable if j not in rset)
         if not any(r.resident for r in self.regions):
             return
-        needed = {v for v in jaxpr.outvars if isinstance(v, jax.core.Var)}
+        needed = {v for v in jaxpr.outvars if isinstance(v, Var)}
         skip = set()
         for i in range(len(self.items) - 1, -1, -1):
             kind, payload = self.items[i]
@@ -525,15 +543,15 @@ class LoweredComputation:
                 rset = {ra.ai for ra in payload.resident}
                 needed.update(
                     a for j, a in enumerate(payload.in_atoms)
-                    if isinstance(a, jax.core.Var) and j not in rset)
+                    if isinstance(a, Var) and j not in rset)
             else:
                 outs = [v for v in payload.outvars
-                        if not isinstance(v, jax.core.DropVar)]
+                        if not isinstance(v, DropVar)]
                 if not any(v in needed for v in outs):
                     skip.add(i)
                 else:
                     needed.update(v for v in payload.invars
-                                  if isinstance(v, jax.core.Var))
+                                  if isinstance(v, Var))
         self._warm_skip = frozenset(skip)
 
     def _build_resident_pack(self, region: Region, ra: ResidentAtom,
@@ -558,7 +576,7 @@ class LoweredComputation:
                                          signed=ra.signed)
         if arr.dtype == jnp.bool_:
             arr = arr.astype(jnp.int32)
-        return PlanePack.pack(arr, ra.n_bits, signed=ra.signed)
+        return _pack_program(arr, ra.n_bits, ra.signed)
 
     # -- execution ----------------------------------------------------------
     def execute(self, *args):
@@ -584,7 +602,7 @@ class LoweredComputation:
             # registry-backed: re-resolve each call so ECC toggles,
             # clear_resident() and failover spec swaps take effect on the
             # next execution instead of pinning into a stale set
-            rs = array_mod.resident_set(self.spec)
+            rs = array_mod.lowering_resident_set(self.spec)
         resident_on = (rs is not None and self.resident_leaf_idx
                        and any(r.resident for r in self.regions)
                        and not any(isinstance(leaves[i], jax.core.Tracer)
@@ -640,7 +658,7 @@ class LoweredComputation:
         if not op.prim.multiple_results:
             vals = [vals]
         for var, val in zip(op.outvars, vals):
-            if not isinstance(var, jax.core.DropVar):
+            if not isinstance(var, DropVar):
                 env[var] = val
 
     def _run_region(self, region: Region, env: Dict[Any, Any],
@@ -716,7 +734,7 @@ class LoweredComputation:
                     var_env[atom] = leaf
 
             def read(atom):
-                if isinstance(atom, jax.core.Literal):
+                if isinstance(atom, Literal):
                     return jnp.asarray(atom.val, dtype=atom.aval.dtype)
                 if isinstance(atom, ConstVal):
                     return const_env[id(atom)]
@@ -726,7 +744,7 @@ class LoweredComputation:
                 """Operand as a PlanePack of logical `shape` (region entry
                 pack for external values — each packed ONCE per region —
                 with scalar fanout staying in the packed domain)."""
-                if isinstance(atom, jax.core.Var) and atom in penv:
+                if isinstance(atom, Var) and atom in penv:
                     p = penv[atom]
                     if p.shape != tuple(shape):
                         p = _broadcast_pack(p, tuple(shape))
@@ -743,7 +761,7 @@ class LoweredComputation:
                 # its planes are driven into rows before the first access
                 # (resident atoms never reach here — they are pre-seeded)
                 cur.charge_load(p.n_bits, p.n_words)
-                if isinstance(atom, jax.core.Var) and \
+                if isinstance(atom, Var) and \
                         tuple(shape) == tuple(aval.shape):
                     penv[atom] = p    # entry pack: reused by later consumers
                 return p
@@ -751,7 +769,7 @@ class LoweredComputation:
             def geti(atom) -> jax.Array:
                 """Operand as an integer array (the dot_general layout
                 rebuild — the one declared in-region materialization)."""
-                if isinstance(atom, jax.core.Var) and atom in penv:
+                if isinstance(atom, Var) and atom in penv:
                     aval = aval_of(atom)
                     return penv[atom].unpack().astype(aval.dtype)
                 return jnp.asarray(read(atom))
@@ -793,7 +811,7 @@ class LoweredComputation:
                     res = chain.reduce_sum(getp(op.invars[0], src_shape))
                 elif name == "dot_general":
                     rb = resident_matmul.get(op.invars[1]) \
-                        if isinstance(op.invars[1], jax.core.Var) else None
+                        if isinstance(op.invars[1], Var) else None
                     nb = len(op.params["dimension_numbers"][1][0])
                     mm = chain.batched_matmul if nb else chain.matmul
                     res = mm(geti(op.invars[0]),
@@ -821,7 +839,7 @@ class LoweredComputation:
                                           shape)
                 else:                             # pragma: no cover
                     raise CimOpError(f"region executor missing op {name!r}")
-                if not isinstance(op.outvars[0], jax.core.DropVar):
+                if not isinstance(op.outvars[0], DropVar):
                     penv[op.outvars[0]] = _finish(res, out_aval)
 
             return tuple(penv[var].unpack().astype(aval_of(var).dtype)

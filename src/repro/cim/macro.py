@@ -44,6 +44,7 @@ Macros:
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional, Sequence, Tuple
 
 import jax
@@ -192,7 +193,7 @@ def aval_sig(aval) -> Tuple:
 def _leaf_sig(x):
     """aval_sig of a concrete (or traced) input leaf."""
     try:
-        return aval_sig(jax.core.get_aval(x))
+        return aval_sig(jax.typeof(x))
     except Exception:
         return aval_sig(jnp.asarray(x))
 
@@ -215,8 +216,8 @@ def run_schedule_program(schedule: planner.Schedule, body, operands,
     exactly as the eager cursor charged.
 
     `donate` names operand leaf positions whose buffers the program may
-    reuse for its accumulator chain (jit donate_argnums); callers must only
-    donate buffers that are dead after the call.
+    reuse for an output of the same shape and dtype (jit donate_argnums);
+    callers must only donate buffers that are dead after the call.
 
     Residency note: a cached program keeps its body closure (for a region:
     the Region and any closed-over ConstVal constants) alive until LRU
@@ -493,12 +494,20 @@ def reduce_sum(a: PlanePack, backend: Optional[str] = None,
 # ---------------------------------------------------------------------------
 
 
+#: the resident pack builders run as one program each: op by op, the
+#: broadcast layout and the codec's per-plane bits would each be held in
+#: device memory (GiBs for one published-width weight)
+_one_program = functools.partial(
+    jax.jit, static_argnames=("m", "n_bits", "signed"))
+
+
+@_one_program
 def matmul_rhs_pack(b: jax.Array, m: int, n_bits: int,
                     signed: bool = True) -> PlanePack:
     """The expanded [M, K_pad, N] rhs entry pack of a matmul — the plane
     stack a ResidentSet pins so warm calls skip building (and loading) it.
-    Built OUTSIDE any trace: the result is a concrete pack whose planes can
-    live in array rows across calls."""
+    Built OUTSIDE any region trace: the result is a concrete pack whose
+    planes can live in array rows across calls."""
     b = jnp.asarray(b)
     if b.ndim != 2:
         raise CimOpError(f"matmul rhs must be [K, N], got {b.shape}")
@@ -509,6 +518,7 @@ def matmul_rhs_pack(b: jax.Array, m: int, n_bits: int,
     return PlanePack.pack(b_exp, n_bits, signed=signed)
 
 
+@_one_program
 def batched_matmul_rhs_pack(b: jax.Array, m: int, n_bits: int,
                             signed: bool = True) -> PlanePack:
     """The expanded [B_flat * M, K_pad, N] rhs entry pack of a batched

@@ -1,7 +1,7 @@
 """jaxpr -> CiM IR: the eligibility front end of the lowering compiler.
 
 `trace(fn, *args)` stages a JAX function with `jax.make_jaxpr`, flattens
-nested `pjit` calls, and classifies every equation into the ADRA cost model:
+nested `jit` calls, and classifies every equation into the ADRA cost model:
 
   single — elementwise integer ops one asymmetric dual-row access computes:
            add / sub / compare (lt, le, gt, ge, eq, ne) / bitwise
@@ -34,6 +34,8 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.core import DropVar, ShapedArray
+from jax.extend.core import ClosedJaxpr, Literal, Var
 
 from . import planner
 
@@ -64,10 +66,10 @@ class ConstVal:
     @property
     def aval(self):
         v = self.val
-        return jax.core.ShapedArray(np.shape(v), jnp.result_type(v))
+        return ShapedArray(np.shape(v), jnp.result_type(v))
 
 
-def aval_of(atom) -> jax.core.ShapedArray:
+def aval_of(atom) -> ShapedArray:
     """aval of a Var, Literal, or ConstVal operand."""
     return atom.aval
 
@@ -97,7 +99,7 @@ class TracedOp:
 class Trace:
     """The flattened, classified eqn list of one staged function."""
 
-    closed: jax.core.ClosedJaxpr
+    closed: ClosedJaxpr
     ops: List[TracedOp]
     out_shape: Any                 # pytree of ShapeDtypeStruct (output tree)
 
@@ -348,23 +350,23 @@ def classify(op: TracedOp) -> None:
 
 
 # ---------------------------------------------------------------------------
-# jaxpr flattening (pjit inlining)
+# jaxpr flattening (nested jit inlining)
 # ---------------------------------------------------------------------------
 
 
 def _flatten(jaxpr, subst: Dict[Any, Any]) -> List[TracedOp]:
-    """Flatten a jaxpr into TracedOps, inlining pjit calls so regions can
+    """Flatten a jaxpr into TracedOps, inlining jit calls so regions can
     fuse across `jnp.where`-style wrappers. `subst` maps this jaxpr's vars
     (invars of an inlined call, constvars) to outer atoms."""
 
     def res(atom):
-        if isinstance(atom, jax.core.Literal):
+        if isinstance(atom, Literal):
             return atom
         return subst.get(atom, atom)
 
     ops: List[TracedOp] = []
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pjit":
+        if eqn.primitive.name == "jit":
             inner = eqn.params["jaxpr"]          # ClosedJaxpr
             inner_subst = dict(
                 zip(inner.jaxpr.invars, (res(v) for v in eqn.invars)))
@@ -378,9 +380,9 @@ def _flatten(jaxpr, subst: Dict[Any, Any]) -> List[TracedOp]:
             out_map: Dict[Any, Any] = {}
             aliases: List[Tuple[Any, Any]] = []
             for iv, ov in zip(inner.jaxpr.outvars, eqn.outvars):
-                if isinstance(ov, jax.core.DropVar):
+                if isinstance(ov, DropVar):
                     continue
-                if isinstance(iv, jax.core.Literal):
+                if isinstance(iv, Literal):
                     aliases.append((iv, ov))
                 elif iv in inner_subst:
                     aliases.append((inner_subst[iv], ov))
@@ -393,7 +395,7 @@ def _flatten(jaxpr, subst: Dict[Any, Any]) -> List[TracedOp]:
                 # consumers INSIDE the inlined jaxpr must follow the rename
                 # (an inner output can also feed further inner eqns)
                 op.invars = tuple(
-                    out_map.get(v, v) if isinstance(v, jax.core.Var) else v
+                    out_map.get(v, v) if isinstance(v, Var) else v
                     for v in op.invars)
             ops.extend(inner_ops)
             ops.extend(

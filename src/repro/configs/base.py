@@ -91,6 +91,10 @@ class ArchConfig:
     #                                 charge (and pin) per call — the serve
     #                                 engine sets this for BOTH sides of the
     #                                 repack-vs-resident comparison
+    cim_policy: str = "edp"         # offload policy of the lowered MLP and
+    #                                 attention (repro.cim.cost): "never"
+    #                                 runs every lowered eqn on the host —
+    #                                 the bit-exact host twin of a CiM run
 
     # -- derived -----------------------------------------------------------
     @property

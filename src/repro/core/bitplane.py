@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 # trace-time codec call counters: the CiM engine's chained-op tests assert
 # that PlanePack pipelines never re-enter these between ops
@@ -63,18 +64,31 @@ def pack_bitplanes(x: jax.Array, n_bits: int) -> jax.Array:
     x = jnp.asarray(x, dtype=jnp.int32).reshape(-1)
     n = x.shape[0]
     pad = (-n) % 32
-    x = jnp.pad(x, (0, pad))
-    bits = int_to_bits(x, n_bits)                        # [N, n_bits]
-    bits = bits.T.reshape(n_bits, -1, 32)                # [n_bits, N/32, 32]
+    # bits plane-major with the lane axis minor, never an [N, n_bits] bit
+    # matrix: that is n_bits words per word, padded on the TPU from a minor
+    # axis of n_bits to 128 lanes — GiBs at a 2048 x 16384 operand
+    x = jnp.pad(x, (0, pad)).reshape(-1, 32).T           # [32, N/32]
+    shifts = jnp.arange(n_bits, dtype=jnp.int32)[:, None, None]
+    bits = (x[None] >> shifts) & 1                       # [n_bits, 32, N/32]
     weights = (1 << jnp.arange(32, dtype=jnp.uint32)).astype(jnp.uint32)
-    return jnp.sum(bits.astype(jnp.uint32) * weights, axis=-1)
+    return jnp.sum(bits.astype(jnp.uint32) * weights[:, None], axis=1)
 
 
 def unpack_bitplanes(planes: jax.Array, n_words: int, signed: bool = True) -> jax.Array:
     """[n_bits, W] uint32 packed planes -> [n_words] int (two's complement)."""
     _CODEC_CALLS["unpack"] += 1
     n_bits, w = planes.shape
-    shifts = jnp.arange(32, dtype=jnp.uint32)
-    bits = (planes[..., None] >> shifts) & jnp.uint32(1)  # [n_bits, W, 32]
-    bits = bits.reshape(n_bits, w * 32).T.astype(jnp.int32)  # [N, n_bits]
-    return bits_to_int(bits[:n_words], signed=signed)
+    # bits_to_int over the plane axis with the lane axis minor (see
+    # pack_bitplanes); only the [32, W] result is transposed to word order.
+    # The sign plane of a signed word narrower than 32 bits weighs -2^(n-1)
+    # (two's complement); sums wrap modulo 2^32 like int32 arithmetic
+    k = min(n_bits, 32)
+    weights = np.left_shift(np.int64(1), np.arange(k, dtype=np.int64))
+    if signed and n_bits < 32:
+        weights[-1] = -weights[-1]
+    weights = jnp.asarray(weights.astype(np.uint32).view(np.int32))
+    shifts = jnp.arange(32, dtype=jnp.uint32)[:, None]
+    bits = ((planes[:k, None, :] >> shifts) & jnp.uint32(1)).astype(jnp.int32)
+    val = jnp.sum(bits * weights[:, None, None], axis=0,
+                  dtype=jnp.int32)                       # [32, W]
+    return val.T.reshape(-1)[:n_words]

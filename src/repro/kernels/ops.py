@@ -7,7 +7,8 @@ platform checks. The legacy `interpret` flag maps onto the pallas-interpret /
 pallas-tpu backends for callers that pin the Pallas path explicitly.
 
 Attention / recurrence wrappers keep the same dispatch idea: Pallas on TPU,
-interpret mode in tests, pure-jnp reference for dry-run lowering.
+interpret mode only where the caller asks for it (tests), pure-jnp reference
+elsewhere.
 """
 from __future__ import annotations
 
@@ -142,10 +143,11 @@ def cim_lower(fn, interpret: bool | None = None, backend: str | None = None,
 
 def attention(q, k, v, causal: bool = True, use_pallas: bool | None = None,
               interpret: bool = False):
-    """GQA attention: Pallas flash kernel on TPU, jnp reference elsewhere."""
+    """GQA attention: Pallas flash kernel on TPU, jnp reference elsewhere.
+    The kernel runs in interpret mode only when the caller asks for it."""
     use_pallas = on_tpu() if use_pallas is None else use_pallas
     if use_pallas or interpret:
-        return _flash(q, k, v, causal=causal, interpret=interpret or not on_tpu())
+        return _flash(q, k, v, causal=causal, interpret=interpret)
     return ref.mha_ref(q, k, v, causal=causal)
 
 
@@ -153,6 +155,5 @@ def rglru_scan(x, r, i, log_lambda, h0=None, c: float = 8.0,
                use_pallas: bool | None = None, interpret: bool = False):
     use_pallas = on_tpu() if use_pallas is None else use_pallas
     if use_pallas or interpret:
-        return _rglru(x, r, i, log_lambda, h0=h0, c=c,
-                      interpret=interpret or not on_tpu())
+        return _rglru(x, r, i, log_lambda, h0=h0, c=c, interpret=interpret)
     return ref.rglru_ref(x, r, i, log_lambda, h0=h0, c=c)

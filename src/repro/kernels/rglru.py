@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
-
 
 def _rglru_kernel(x_ref, r_ref, i_ref, ll_ref, h0_ref, y_ref, hout_ref, h_ref, *, c: float):
     ti = pl.program_id(2)
@@ -93,7 +91,7 @@ def rglru(
             jax.ShapeDtypeStruct((b, d), jnp.float32),
         ),
         scratch_shapes=[pltpu.VMEM((1, block_d), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

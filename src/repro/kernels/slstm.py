@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
-
 
 def _slstm_kernel(wx_ref, r_ref, b_ref, h0_ref, c0_ref, n0_ref, m0_ref,
                   y_ref, hout_ref, cout_ref, nout_ref, mout_ref, state_ref):
@@ -115,7 +113,7 @@ def slstm_scan(
             jax.ShapeDtypeStruct((bp, d), jnp.float32),
         ),
         scratch_shapes=[pltpu.VMEM((4, block_b, d), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

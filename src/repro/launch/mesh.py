@@ -7,7 +7,13 @@ host platform to initialize first.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def make_mesh(shape, axes) -> Mesh:
+    """`jax.make_mesh` with Auto axis types: the sharding rules here are
+    GSPMD hints (with_sharding_constraint), which explicit axes reject."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -19,7 +25,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_smoke_mesh(n_devices: int | None = None) -> Mesh:
@@ -30,7 +36,7 @@ def make_smoke_mesh(n_devices: int | None = None) -> Mesh:
         if n % cand == 0:
             model = cand
             break
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))
 
 
 def elastic_mesh_shape(n_devices: int, prefer_model: int = 16) -> tuple:

@@ -18,21 +18,22 @@ per-token latencies EXCLUDE prefill and the first `--warmup-steps` decode
 steps (compile happens there); prefill cost is reported separately per
 request (`prefill_ms`).
 
-Charge semantics with --cim-lower: the decode step runs UNJITTED (the
-grouped-layer scan is unrolled, see ArchConfig.cim_unroll_groups) so every
-step's lowered MLP regions charge the ledger per call — `accesses` is the
-compute bill, `load_accesses` the streamed-operand row-write bill. The
-jitted prefill still charges once at trace time (labeled: it lands on the
-first request). Per-request attribution splits each decode step's ledger
-delta evenly across the slots active in that step.
+Charge semantics with --cim-lower: prefill and decode steps run UNJITTED
+(the grouped-layer scan is unrolled, see ArchConfig.cim_unroll_groups) so
+every step's lowered regions charge the ledger per call — `accesses` is
+the compute bill, `load_accesses` the streamed-operand row-write bill. A
+prefill's charges land on its request; per-request attribution splits
+each decode step's ledger delta evenly across the slots active in that
+step.
 
 --cim-resident pins the int8 MLP weight planes in the arrays' resident
 rows (repro.cim.lower resident mode): warm decode steps charge ZERO loads
-for the weight side. The --cim-lower bench mode runs the SAME request
-schedule twice — streamed repack, then resident — and asserts the resident
-phase's total accesses/token is strictly lower at identical compute
-accesses/token; --assert-warm replays the resident phase and asserts no
-program-cache misses and no new pins (everything stayed warm).
+for the weight side (prefills stream theirs). The --cim-lower bench mode
+runs the SAME request schedule twice — streamed repack, then resident —
+and asserts the resident phase's total accesses/token is strictly lower
+at identical compute accesses/token; --assert-warm replays the resident
+phase and asserts no program-cache misses and no new pins (everything
+stayed warm).
 """
 from __future__ import annotations
 
@@ -46,6 +47,7 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.launch.compile_cache import setup_compile_cache
 from repro.launch.paged_kv import PagedKV
 from repro.launch.train import preset_config
 from repro.models import build
@@ -145,10 +147,13 @@ class ServeEngine:
         self.shed_count = 0
         self.scrub_report = {"scanned": 0, "dropped": 0,
                              "corrected": 0, "uncorrected": 0}
-        self.prefill_fn = jax.jit(make_prefill_step(model, max_len))
-        dec = make_decode_step(model)
+        pre, dec = make_prefill_step(model, max_len), make_decode_step(model)
         # unjitted with --cim-lower: lowered regions then execute (and
-        # charge) per call, which is what residency accelerates
+        # charge) per call, which is what residency accelerates, and every
+        # host eqn runs as its own program, exactly as in a host-policy
+        # twin (one program around the regions would fuse, and round, the
+        # float ops next to them differently from the twin's program)
+        self.prefill_fn = pre if cim_lower else jax.jit(pre)
         self.decode_fn = dec if cim_lower else \
             jax.jit(dec, donate_argnums=(1,))
         self._insert = jax.jit(self._insert_slot)
@@ -187,22 +192,27 @@ class ServeEngine:
         new_rs = array_mod.resident_set(new_spec)
         if self.paged is not None:
             self.paged.migrate(new_spec, new_rs)
-        old_rs = array_mod._RESIDENT_SETS.get(self.spec)
-        if old_rs is not None and old_rs is not new_rs:
-            old_rs.clear()              # stale pins: re-pin under new_spec
+        # stale pins (the banked set's, and the unbanked weight pins of the
+        # healthy spec=None layers): they re-pin under new_spec
+        for key in (self.spec, None):
+            old_rs = array_mod._RESIDENT_SETS.get(key)
+            if old_rs is not None and old_rs is not new_rs:
+                old_rs.clear()
         array_mod.set_current_spec(new_spec)
         self.spec = new_spec
         self.failovers += 1
 
     def _scrub(self) -> None:
+        """One ECC scrub pass over every registry set: the engine's banked
+        set (KV blocks) and the set its lowered layers pin weights into."""
         from repro.cim import array as array_mod
 
-        rs = array_mod._RESIDENT_SETS.get(self.spec)
-        if rs is None or not rs.ecc:
-            return
-        r = rs.scrub()
-        for k in self.scrub_report:
-            self.scrub_report[k] += r.get(k, 0)
+        for rs in list(array_mod._RESIDENT_SETS.values()):
+            if not rs.ecc:
+                continue
+            r = rs.scrub()
+            for k in self.scrub_report:
+                self.scrub_report[k] += r.get(k, 0)
 
     @staticmethod
     def _insert_slot(batched, single, slot):
@@ -459,13 +469,13 @@ class ServeEngine:
 # ---------------------------------------------------------------------------
 
 
-def _requests(args) -> List[ServeRequest]:
+def make_requests(args) -> List[ServeRequest]:
     return [ServeRequest(rid=i, prompt_len=args.prompt_len, gen=args.gen,
                          arrival_s=i * args.arrival_interval)
             for i in range(args.requests)]
 
 
-def _fresh_cim_state() -> None:
+def reset_cim_state() -> None:
     from repro.cim import clear_schedule_cache
     from repro.cim import cost as _cost
     from repro.cim import faults as faults_mod
@@ -478,7 +488,8 @@ def _fresh_cim_state() -> None:
     faults_mod.reset_fault_stats()
 
 
-def _serve_once(model, params, args) -> Dict[str, Any]:
+def build_engine(model, params, args) -> ServeEngine:
+    """The engine `main` serves with, sized from the parsed arguments."""
     cfg = model.cfg
     spec = None
     rs = None
@@ -489,13 +500,34 @@ def _serve_once(model, params, args) -> Dict[str, Any]:
     paged = PagedKV.for_model(cfg, spec=spec, slots=args.slots,
                               max_len=args.prompt_len + args.gen,
                               resident_set=rs)
-    engine = ServeEngine(model, params, slots=args.slots,
-                         max_len=args.prompt_len + args.gen,
-                         sampler=args.sampler, cim_lower=args.cim_lower,
-                         paged=paged, warmup_steps=args.warmup_steps,
-                         spec=spec,
-                         scrub_every=getattr(args, "scrub_every", 0))
-    return engine.run(_requests(args))
+    return ServeEngine(model, params, slots=args.slots,
+                       max_len=args.prompt_len + args.gen,
+                       sampler=args.sampler, cim_lower=args.cim_lower,
+                       paged=paged, warmup_steps=args.warmup_steps,
+                       spec=spec, scrub_every=getattr(args, "scrub_every", 0))
+
+
+def _serve_once(model, params, args) -> Dict[str, Any]:
+    return build_engine(model, params, args).run(make_requests(args))
+
+
+def check_residency(repack: Dict[str, Any], resident: Dict[str, Any]) -> None:
+    """The repack-vs-resident contract of a --cim-lower run: residency
+    leaves the compute bill per token unchanged, strictly lowers the total
+    bill, and reuses pinned operands. Raises AssertionError otherwise."""
+    if resident["accesses_per_token"] != repack["accesses_per_token"]:
+        raise AssertionError(
+            f"compute accesses/token must not change with residency: "
+            f"{resident['accesses_per_token']} != "
+            f"{repack['accesses_per_token']}")
+    if not resident["total_accesses_per_token"] \
+            < repack["total_accesses_per_token"]:
+        raise AssertionError(
+            f"resident serving must charge strictly fewer total "
+            f"accesses/token: {resident['total_accesses_per_token']} !< "
+            f"{repack['total_accesses_per_token']}")
+    if resident["ledger"]["resident_reuses"] <= 0:
+        raise AssertionError("resident serving reused no pinned operand")
 
 
 def _print_cim_report(tag: str) -> None:
@@ -527,7 +559,7 @@ def _print_cim_report(tag: str) -> None:
           f"{ps['fused_despite_loss']} losing eqns kept fused")
 
 
-def main():
+def parse_args(argv: Optional[List[str]] = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma-2b")
     ap.add_argument("--preset", default="reduced",
@@ -565,19 +597,37 @@ def main():
                          "bit-identical tokens to the fault-free phase")
     ap.add_argument("--scrub-every", type=int, default=0,
                     help="decode steps between ECC scrub passes (0: off)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.requests <= 0:
         args.requests = args.slots
+    return args
 
+
+def serve_config(args):
+    """The served ArchConfig. Weights are held in the activation dtype:
+    the per-layer compute cast then returns them unchanged, so they keep
+    the identity residency keys on, and they take half the memory of
+    float32 master weights at the bfloat16 presets."""
     cfg = preset_config(args.arch, args.preset)
+    cfg = dataclasses.replace(cfg, param_dtype=cfg.dtype)
     if args.cim_lower:
         cfg = dataclasses.replace(cfg, cim_mlp_bits=args.cim_bits,
                                   cim_attention_bits=args.cim_bits,
                                   cim_unroll_groups=True)
     if args.cim_resident and not args.cim_lower:
         cfg = dataclasses.replace(cfg, cim_resident=True)
+    return cfg
+
+
+def main(argv: Optional[List[str]] = None):
+    args = parse_args(argv)
+    print(f"compile cache: {setup_compile_cache()}")
+    cfg = serve_config(args)
     model = build(cfg)
     params = model.init(jax.random.PRNGKey(0))
+    if args.cim_lower:
+        # lowered serving runs the layers unrolled: hold them unstacked
+        params = model.unstack_groups(params)
 
     out: Dict[str, Any] = {
         "bench": "serve", "arch": args.arch, "preset": args.preset,
@@ -599,25 +649,17 @@ def main():
         # one model per phase, built ONCE: the resident model's memoized
         # param slices must keep their identity for the warm replay
         model_resident = build(dataclasses.replace(cfg, cim_resident=True))
+        from repro.cim import default_backend_name
+        print(f"cim backend: {default_backend_name()}")
         # phase 1: streamed repack — every decode step re-packs the weights
-        _fresh_cim_state()
+        reset_cim_state()
         repack = _serve_once(model, params, args)
         _print_cim_report("repack")
         # phase 2: resident — weight planes pinned at first touch
-        _fresh_cim_state()
+        reset_cim_state()
         resident = _serve_once(model_resident, params, args)
         _print_cim_report("resident")
-
-        assert resident["accesses_per_token"] == repack["accesses_per_token"], \
-            (f"compute accesses/token must not change with residency: "
-             f"{resident['accesses_per_token']} != "
-             f"{repack['accesses_per_token']}")
-        assert resident["total_accesses_per_token"] \
-            < repack["total_accesses_per_token"], \
-            (f"resident serving must charge strictly fewer total "
-             f"accesses/token: {resident['total_accesses_per_token']} !< "
-             f"{repack['total_accesses_per_token']}")
-        assert resident["ledger"]["resident_reuses"] > 0
+        check_residency(repack, resident)
 
         if args.assert_warm:
             from repro.cim import cache_stats
@@ -661,7 +703,7 @@ def main():
             # tok/s includes verify overhead by design).
             from repro.cim import array as array_mod
             from repro.cim import faults as faults_mod
-            _fresh_cim_state()
+            reset_cim_state()
             array_mod.set_resident_ecc(True)
             fcfg = faults_mod.FaultConfig.from_env(
                 raise_on_uncorrectable=True)

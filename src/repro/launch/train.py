@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_config
 from repro.data import DataConfig, embed_stub_batch, synthetic_batch
+from repro.launch.compile_cache import setup_compile_cache
 from repro.launch.mesh import make_smoke_mesh
 from repro.models import build
 from repro.optim import AdamWConfig, cosine_schedule
@@ -55,6 +56,7 @@ def main():
     ap.add_argument("--compress-grads", action="store_true")
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    setup_compile_cache()
 
     cfg = preset_config(args.arch, args.preset)
     model = build(cfg)

@@ -101,23 +101,25 @@ def _sdpa_quantized(q, k, v, mask, scale, n_bits: int = 8) -> jax.Array:
 _LOWERED_SDPA: "OrderedDict" = OrderedDict()
 
 
-def _lowered_sdpa(n_bits: int, backend, spec, mesh, resident: bool = False):
+def _lowered_sdpa(n_bits: int, backend, spec, mesh, resident: bool = False,
+                  policy: str | None = None):
     from repro.cim import array
     from repro.cim.lower import lower
 
     return _lru_get(
-        _LOWERED_SDPA, (n_bits, backend, spec, mesh, resident),
+        _LOWERED_SDPA, (n_bits, backend, spec, mesh, resident, policy),
         lambda: lower(
             lambda qs, k, v, mask: _sdpa_quantized_core(qs, k, v, mask,
                                                         n_bits),
             backend=backend, spec=spec, mesh=mesh,
             resident_argnums=(1, 2) if resident else (),
-            resident_set=array.resident_set(spec) if resident else None))
+            resident_set=array.resident_set(spec) if resident else None,
+            policy=policy))
 
 
 def sdpa_cim(q, k, v, mask, scale, n_bits: int = 8,
              backend: str | None = None, spec=None, mesh=None,
-             resident: bool = False) -> jax.Array:
+             resident: bool = False, policy: str | None = None) -> jax.Array:
     """Grouped SDPA with QK^T and AV executed as planned CiM schedules.
 
     Two fused regions per call (one per contraction) — warm calls are
@@ -125,9 +127,10 @@ def sdpa_cim(q, k, v, mask, scale, n_bits: int = 8,
     `resident=True` pins the packed K^T/V planes by array identity: pass
     the SAME k/v arrays across calls to skip their entry packs (decode with
     a functionally-updated cache gets fresh arrays each step, so the serve
-    path streams KV instead — see `gqa_decode_cim`)."""
+    path streams KV instead — see `gqa_decode_cim`). `policy` is the
+    lowering's offload policy (see `layers.mlp_cim`)."""
     qs = q.astype(jnp.float32) * jnp.asarray(scale, jnp.float32)
-    lf = _lowered_sdpa(n_bits, backend, spec, mesh, resident)
+    lf = _lowered_sdpa(n_bits, backend, spec, mesh, resident, policy)
     return lf(qs, k, v, mask).astype(q.dtype)
 
 
@@ -258,7 +261,7 @@ def gqa_decode_cim(p, cfg: ArchConfig, x, cache: Params, positions
     t_max = ck.shape[1]
     valid = jnp.arange(t_max)[None, :] <= positions[:, None]
     o = sdpa_cim(q, ck, cv, valid[:, None, :], 1.0 / cfg.head_dim ** 0.5,
-                 n_bits=cfg.cim_attention_bits)
+                 n_bits=cfg.cim_attention_bits, policy=cfg.cim_policy)
     y = jnp.einsum("bthk,hkd->btd", o, p["wo"],
                    preferred_element_type=jnp.float32).astype(x.dtype)
     return y, {"k": ck, "v": cv}

@@ -235,14 +235,15 @@ def _lowered_linear(n_bits: int, backend, spec, mesh, resident: bool = False):
 
 
 def _lowered_mlp(gating: str, n_bits: int, backend, spec, mesh,
-                 resident: bool = False):
+                 resident: bool = False, policy: str | None = None):
     from repro.cim.lower import lower
 
     return _lru_get(
-        _LOWERED_MLP, (gating, n_bits, backend, spec, mesh, resident),
+        _LOWERED_MLP, (gating, n_bits, backend, spec, mesh, resident, policy),
         lambda: lower(lambda p, x: _mlp_quantized(p, x, gating, n_bits),
                       backend=backend, spec=spec, mesh=mesh,
-                      resident_argnums=(0,) if resident else ()))
+                      resident_argnums=(0,) if resident else (),
+                      policy=policy))
 
 
 def cim_linear(x: jax.Array, w: jax.Array, n_bits: int = 8,
@@ -278,17 +279,20 @@ def cim_linear(x: jax.Array, w: jax.Array, n_bits: int = 8,
 
 def mlp_cim(p: Params, x: jax.Array, gating: str, n_bits: int = 8,
             backend: str | None = None, spec=None, mesh=None,
-            resident: bool = False) -> jax.Array:
+            resident: bool = False, policy: str | None = None) -> jax.Array:
     """The MLP compiled through the jaxpr->CiM lowering pass: every integer
     matmul executes in the CiM array, every float op (quantization scales,
     SiLU/GELU gating) on the host — the opt-in twin of `mlp` for offload
     studies on reduced configs. `resident=True` pins the int8 weight planes
     across calls (see cim_linear). `spec=None` resolves through
-    `array.spec_override()` — bank failover re-routes here too."""
+    `array.spec_override()` — bank failover re-routes here too. `policy` is
+    the lowering's offload policy (repro.cim.cost; "never" keeps every eqn
+    on the host: the bit-exact host twin)."""
     if spec is None:
         from repro.cim import array
         spec = array.spec_override()
-    return _lowered_mlp(gating, n_bits, backend, spec, mesh, resident)(p, x)
+    return _lowered_mlp(gating, n_bits, backend, spec, mesh, resident,
+                        policy)(p, x)
 
 
 # ---------------------------------------------------------------------------

@@ -52,12 +52,16 @@ Params = Dict[str, Any]
 # ---------------------------------------------------------------------------
 
 
-def _apply_mlp(cfg: ArchConfig, p: Params, h):
+def _apply_mlp(cfg: ArchConfig, p: Params, h, mode: str):
     """Dense-MLP dispatch: the jaxpr->CiM lowered quantized path when the
-    config opts in (cim_mlp_bits > 0), the plain dense path otherwise."""
+    config opts in (cim_mlp_bits > 0), the plain dense path otherwise.
+    Weight planes are pinned for decode only: the broadcast [M, K_pad, N]
+    layout pins one plane stack per row count, and a prefill's rows (its
+    prompt) would pin another beside the decode batch's."""
     if cfg.cim_mlp_bits:
         return mlp_cim(p, h, cfg.gating, n_bits=cfg.cim_mlp_bits,
-                       resident=cfg.cim_resident)
+                       resident=cfg.cim_resident and mode == "decode",
+                       policy=cfg.cim_policy)
     return mlp(p, h, cfg.gating)
 
 
@@ -163,7 +167,7 @@ def _layer_apply(
         if cfg.moe is not None and layer_idx >= cfg.first_dense_layers:
             y2, aux = moe_lib.moe_apply(p["mlp"], cfg, h2)
         else:
-            y2 = _apply_mlp(cfg, p["mlp"], h2)
+            y2 = _apply_mlp(cfg, p["mlp"], h2, mode)
         return x + y2, aux, new_cache
 
     if kind == "rec":
@@ -171,7 +175,7 @@ def _layer_apply(
         y, new_state = rec_lib.rglru_block_apply(p["rec"], cfg, h, state)
         x = x + y
         h2 = rmsnorm(p["ln2"], x, cfg.norm_eps)
-        x = x + _apply_mlp(cfg, p["mlp"], h2)
+        x = x + _apply_mlp(cfg, p["mlp"], h2, mode)
         new_cache = new_state if mode in ("prefill", "decode") else None
         return x, aux, new_cache
 
@@ -339,20 +343,20 @@ class Model:
             body = jax.checkpoint(period_body, prevent_cse=False)
 
         if lay.n_groups > 0:
-            xs = (
-                params["groups"],
-                caches["groups"] if caches is not None else None,
-            )
             # resident serving unrolls the group scan: inside lax.scan the
             # per-layer params are Tracers, whose identity is per-trace, so
             # the lowered MLPs could never hold a warm pin. The unrolled
-            # path hands each layer the SAME memoized param slice every
-            # call (train keeps the scan: remat + compile time matter more)
-            if (cfg.cim_resident or cfg.cim_unroll_groups) \
-                    and mode != "train":
+            # path hands each layer the SAME param slice every call: the
+            # per-group params of `unstack_groups`, or else memoized slices
+            # of the stacked ones (train keeps the scan: remat + compile
+            # time matter more)
+            unstacked = "group_layers" in params
+            if unstacked or ((cfg.cim_resident or cfg.cim_unroll_groups)
+                             and mode != "train"):
                 carry = (x, aux_total)
                 ncs_stacked = []
-                slices = self._group_param_slices(params["groups"])
+                slices = (params["group_layers"] if unstacked else
+                          self._group_param_slices(params["groups"]))
                 for g, gp in enumerate(slices):
                     gc = (jax.tree.map(lambda a: a[g], caches["groups"])
                           if caches is not None else None)
@@ -362,6 +366,8 @@ class Model:
                 new_caches["groups"] = jax.tree.map(
                     lambda *xs_: jnp.stack(xs_), *ncs_stacked)
             else:
+                xs = (params["groups"],
+                      caches["groups"] if caches is not None else None)
                 (x, aux_total), group_caches_new = jax.lax.scan(
                     body, (x, aux_total), xs)
                 new_caches["groups"] = group_caches_new
@@ -382,7 +388,9 @@ class Model:
         params object and reused verbatim thereafter — the stability the
         resident fingerprints (id-based, see repro.cim.lower) depend on.
         The cache entry keeps a strong reference to the keyed object so a
-        recycled id() can never alias a dead pytree."""
+        recycled id() can never alias a dead pytree. The slices are copies
+        held beside the stacked params: at published widths, serve
+        `unstack_groups` params instead."""
         key = id(groups)
         hit = self._group_slices.get(key)
         if hit is not None and hit[0] is groups:
@@ -391,6 +399,18 @@ class Model:
                   for g in range(self.layout.n_groups)]
         self._group_slices[key] = (groups, slices)
         return slices
+
+    def unstack_groups(self, params: Params) -> Params:
+        """`params` with the stacked "groups" replaced by "group_layers":
+        one tuple of per-layer params per group, which the stack then
+        runs unrolled. The stacked arrays are not kept, so once the caller
+        drops `params` the group weights are held once, where the memoized
+        slices of a stacked tree hold them twice."""
+        out = {k: v for k, v in params.items() if k != "groups"}
+        out["group_layers"] = [
+            jax.tree.map(lambda a: a[g], params["groups"])
+            for g in range(self.layout.n_groups)]
+        return out
 
     # -- public paths -----------------------------------------------------------
 

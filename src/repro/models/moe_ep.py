@@ -31,20 +31,6 @@ from repro.configs.base import ArchConfig
 Params = Dict[str, Any]
 
 
-def _shard_map(body, mesh, in_specs, out_specs):
-    """Version-portable shard_map: jax>=0.6 exposes jax.shard_map with
-    check_vma; older releases ship jax.experimental.shard_map with check_rep.
-    Replication checking is disabled on both paths (the psum combine is the
-    only collective and its spec is explicit)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
 def _local_moe(router, w_in, w_gate, w_out, xf, *, cfg: ArchConfig,
                e_local: int, axis: str):
     """Per-shard body: route local tokens to LOCAL experts, psum the combine.
@@ -106,11 +92,13 @@ def moe_apply_ep(p: Params, cfg: ArchConfig, x: jax.Array, mesh: Mesh,
     dp = "data" if "data" in mesh.axis_names else mesh.axis_names[0]
 
     body = functools.partial(_local_moe, cfg=cfg, e_local=e_local, axis=axis)
-    fn = _shard_map(
-        body, mesh,
+    # replication checking off: the psum combine is the only collective
+    # and its spec is explicit
+    fn = jax.shard_map(
+        body, mesh=mesh,
         in_specs=(P(), P(axis, None, None), P(axis, None, None),
                   P(axis, None, None), P(dp, None)),
-        out_specs=P(dp, None),
+        out_specs=P(dp, None), check_vma=False,
     )
     y = fn(p["router"], p["w_in"], p["w_gate"], p["w_out"], x.reshape(b * t, d))
     return y.reshape(b, t, d)

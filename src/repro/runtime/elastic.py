@@ -9,18 +9,17 @@ from __future__ import annotations
 
 from typing import Any
 
-import jax
 from jax.sharding import Mesh
 
 from repro.checkpoint import CheckpointManager
 from repro.configs.base import ArchConfig
-from repro.launch.mesh import elastic_mesh_shape
+from repro.launch.mesh import elastic_mesh_shape, make_mesh
 from repro.sharding import state_specs, to_named
 
 
 def plan_mesh(n_devices: int, prefer_model: int = 16) -> Mesh:
     shape = elastic_mesh_shape(n_devices, prefer_model)
-    return jax.make_mesh(shape, ("data", "model"))
+    return make_mesh(shape, ("data", "model"))
 
 
 def restore_on_mesh(

@@ -296,6 +296,23 @@ def test_donation_marks_dead_host_intermediates():
     assert len(region.donatable) == 1
 
 
+def test_donation_only_names_inputs_an_output_can_reuse():
+    """A dead intermediate is donated only where the region has an output
+    of its shape and dtype: XLA can reuse a donated buffer for nothing
+    else, and warns of each one it cannot use."""
+    def fn(x):
+        h = jnp.sin(x.astype(jnp.float32))           # host island
+        q = jnp.round(h * 7.0).astype(jnp.int16)     # dead after region
+        return jnp.sum(q * 2)                        # scalar output
+
+    x = jnp.arange(-8, 8, dtype=jnp.int16)
+    comp = cim.lower(fn, backend="jnp-boolean").trace(x)
+    (region,) = comp.regions
+    assert region.donatable == ()
+    np.testing.assert_array_equal(
+        np.array(cim.lower(fn, backend="jnp-boolean")(x)), np.array(fn(x)))
+
+
 def test_failed_invocation_charges_nothing():
     """A program whose execution raises must leave the ledger and the
     dispatch counter untouched — accounting follows execution, not intent."""

@@ -189,6 +189,32 @@ def _cim_test_model(name="serve-chaos-test", resident=True):
     return model, model.init(jax.random.PRNGKey(1))
 
 
+def test_unstacked_group_params_serve_identically():
+    """`Model.unstack_groups` params: the same tokens as the stacked
+    params they came from, resident pins reused, and no memoized slices
+    (the second copy of the weights that unstacking avoids)."""
+    from repro.configs.base import ArchConfig
+
+    cfg = ArchConfig(name="serve-unstack-test", family="dense", n_layers=2,
+                     d_model=16, n_heads=4, n_kv_heads=2, head_dim=8,
+                     d_ff=32, vocab_size=64, dtype="float32",
+                     tensor_parallel=False, cim_mlp_bits=8,
+                     cim_unroll_groups=True, cim_resident=True)
+    stacked = build(cfg).init(jax.random.PRNGKey(1))
+    reps = []
+    for unstack in (False, True):
+        _fresh_cim()
+        model = build(cfg)
+        params = model.unstack_groups(stacked) if unstack else stacked
+        rep, _, _ = _serve_cim(model, params, reqs=3, gen=3)
+        reps.append(rep)
+    assert "groups" not in params and len(params["group_layers"]) == 2
+    assert not model._group_slices
+    assert [r["token_ids"] for r in reps[0]["per_request"]] == \
+        [r["token_ids"] for r in reps[1]["per_request"]]
+    assert reps[1]["ledger"]["resident_reuses"] > 0
+
+
 def _fresh_cim():
     from repro.cim import clear_schedule_cache
     from repro.cim import cost as cost_mod

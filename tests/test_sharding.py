@@ -86,6 +86,7 @@ def test_sharded_train_step_runs_on_8_devices():
     out = _run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.configs import get_config
+        from repro.launch.mesh import make_mesh
         from repro.models import build
         from repro.optim import AdamWConfig
         from repro.sharding import batch_specs, state_specs, to_named
@@ -93,7 +94,7 @@ def test_sharded_train_step_runs_on_8_devices():
 
         cfg = get_config("llama3.2-1b").reduced()
         model = build(cfg)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         opt = AdamWConfig(lr=1e-3)
         state = init_state(model, jax.random.PRNGKey(0), opt)
         st = to_named(mesh, state_specs(cfg, state, mesh))
