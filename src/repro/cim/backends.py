@@ -96,7 +96,9 @@ def get_backend(name: Optional[str] = None) -> Backend:
 
 
 def _pallas_backend(a_planes, b_planes, ops, *, interpret: bool):
-    return fused_planes_op(a_planes, b_planes, tuple(ops), interpret=interpret)
+    with jax.named_scope("cim.kernel"):
+        return fused_planes_op(a_planes, b_planes, tuple(ops),
+                               interpret=interpret)
 
 
 # ---------------------------------------------------------------------------

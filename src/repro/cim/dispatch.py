@@ -15,7 +15,9 @@ Two substrate services live here as well:
     dispatch covering every access of a macro or fused region. `cache_stats()`
     exposes hit/miss/eviction counters plus `dispatches`, the number of
     jitted-program invocations — the deterministic walltime proxy the
-    benchmarks gate on (a warm macro matmul is exactly ONE dispatch).
+    benchmarks gate on (a warm macro matmul is exactly ONE dispatch) — and
+    `host_eqns`, the eqns the lowering executor bound one at a time on the
+    host (repro.cim.lower).
   * a `jax.shard_map` path over the production/smoke meshes of
     repro.launch.mesh: pass `mesh=` and tiles are block-distributed over the
     mesh's "data" axis, each device executing (and its ledger slice being
@@ -140,6 +142,7 @@ _HITS = 0
 _MISSES = 0
 _EVICTIONS = 0
 _DISPATCHES = 0
+_HOST_EQNS = 0
 
 
 def cache_stats() -> Dict[str, int]:
@@ -147,13 +150,16 @@ def cache_stats() -> Dict[str, int]:
     the program table plus `dispatches`, the total number of jitted-program
     invocations (whole-schedule step programs and per-step tiled programs
     alike). A warm macro or fused region costs exactly one dispatch.
+    `host_eqns` counts the eqns lowered functions executed on the host,
+    each its own eager dispatch (eqns a warm resident call skips are not
+    counted).
     Resident-region counters (resident_pins/hits/misses/evictions/
     invalidations, aggregated across every ResidentSet) ride along so one
     call answers both "did the program cache stay warm" and "did the
     operands stay pinned"."""
     stats = {"hits": _HITS, "misses": _MISSES, "entries": len(_PROGRAMS),
              "evictions": _EVICTIONS, "capacity": _CAPACITY,
-             "dispatches": _DISPATCHES}
+             "dispatches": _DISPATCHES, "host_eqns": _HOST_EQNS}
     stats.update(array_mod.resident_stats())
     from . import faults as faults_mod
 
@@ -162,18 +168,25 @@ def cache_stats() -> Dict[str, int]:
 
 
 def clear_schedule_cache() -> None:
-    global _HITS, _MISSES, _EVICTIONS, _DISPATCHES
+    global _HITS, _MISSES, _EVICTIONS, _DISPATCHES, _HOST_EQNS
     _PROGRAMS.clear()
     _HITS = 0
     _MISSES = 0
     _EVICTIONS = 0
     _DISPATCHES = 0
+    _HOST_EQNS = 0
 
 
 def count_dispatch(n: int = 1) -> None:
     """Record `n` jitted-program invocations (see cache_stats)."""
     global _DISPATCHES
     _DISPATCHES += n
+
+
+def count_host_eqns(n: int) -> None:
+    """Record `n` eqns executed on the host by a lowered function."""
+    global _HOST_EQNS
+    _HOST_EQNS += n
 
 
 def program_cache_get(key):

@@ -22,6 +22,14 @@
   3. Everything else executes on the host, eqn by eqn, exactly as
      evaluating the jaxpr would.
 
+Each call is visible on the profiler's host plane: a `cim.call` span
+(carrying the lowered function's name), a `cim.host` span around each run
+of consecutive host eqns (an island, carrying the eqns it binds) and a
+`cim.region` span around each region's dispatch (carrying its index). The
+region programs are named `cim_<name>_r<index>` on the device plane.
+`dispatch.cache_stats()["host_eqns"]` totals the islands' `eqns`, the
+host eqns bound (the serve CLI prints it).
+
 The hybrid callable is bit-exact with the original function: every CiM op
 result is truncated/extended to its eqn's output dtype in the packed domain
 (free peripheral wiring), so int8 wrap-around, unsigned arithmetic and bool
@@ -43,6 +51,7 @@ feeding a contraction is unpacked first. Elementwise chains never repack.
 from __future__ import annotations
 
 import dataclasses
+import re
 from collections import Counter, OrderedDict
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -51,7 +60,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import cost as cost_mod
-from . import macro, planner
+from . import dispatch, macro, planner
 from . import array as array_mod
 from . import trace as trace_mod
 from .array import ArraySpec
@@ -370,7 +379,8 @@ class LoweredComputation:
 
     `execute(*args)` runs the hybrid program; `describe()` prints the
     region structure and fused schedules; `accesses` is the exact unbanked
-    ledger charge of one execution.
+    ledger charge of one execution. `name` labels its spans and region
+    programs.
     """
 
     def __init__(self, tr: trace_mod.Trace,
@@ -378,8 +388,9 @@ class LoweredComputation:
                  spec: Optional[ArraySpec] = None, mesh=None,
                  resident_leaf_idx: Tuple[int, ...] = (),
                  resident_set=None, policy: Optional[str] = None,
-                 device=None):
+                 device=None, name: str = "fn"):
         self.trace = tr
+        self.name = name
         self.backend = backend
         self.spec = spec
         self.mesh = mesh
@@ -402,6 +413,7 @@ class LoweredComputation:
         self._warm_skip: frozenset = frozenset()
         self._build()
         self._plan_residency()
+        self._steps = self._plan_islands()
 
     # -- structure ----------------------------------------------------------
     def _build(self) -> None:
@@ -554,6 +566,23 @@ class LoweredComputation:
                                   if isinstance(v, Var))
         self._warm_skip = frozenset(skip)
 
+    def _plan_islands(self) -> List[Tuple[str, Any]]:
+        """The items as executed: each region alone, each maximal run of
+        consecutive host eqns as one island of (cold eqns, warm eqns), the
+        warm list without the eqns a warm resident call skips."""
+        steps: List[Tuple[str, Any]] = []
+        for i, (kind, payload) in enumerate(self.items):
+            if kind == "region":
+                steps.append(("region", payload))
+                continue
+            if not steps or steps[-1][0] != "host":
+                steps.append(("host", ([], [])))
+            cold, warm = steps[-1][1]
+            cold.append(payload)
+            if i not in self._warm_skip:
+                warm.append(payload)
+        return steps
+
     def _build_resident_pack(self, region: Region, ra: ResidentAtom,
                              value) -> PlanePack:
         """The concrete plane stack a ResidentSet pins for one atom —
@@ -580,6 +609,12 @@ class LoweredComputation:
 
     # -- execution ----------------------------------------------------------
     def execute(self, *args):
+        with jax.profiler.TraceAnnotation("cim.call", fn=self.name):
+            return self._execute(args)
+
+    __call__ = execute
+
+    def _execute(self, args):
         leaves = jax.tree_util.tree_leaves(args)
         invars = self.trace.closed.jaxpr.invars
         if len(leaves) != len(invars):
@@ -622,31 +657,37 @@ class LoweredComputation:
                 rs.peek(("lowered", id(self), r.index, ra.ai) + fp, fp)
                 for r in self.regions for ra in r.resident)
 
-        for i, (kind, payload) in enumerate(self.items):
+        for kind, payload in self._steps:
             if kind == "host":
-                if warm and i in self._warm_skip:
-                    continue
-                self._run_host(payload, env)
+                ops = payload[1] if warm else payload[0]
+                if ops:
+                    n = len(ops)
+                    with jax.profiler.TraceAnnotation("cim.host", eqns=n):
+                        for op in ops:
+                            self._run_host(op, env)
+                    dispatch.count_host_eqns(n)
                 continue
-            rmap = None
-            if resident_on and payload.resident:
-                rmap = {}
-                for ra in payload.resident:
-                    key = ("lowered", id(self), payload.index, ra.ai) + fp
-                    entry = rs.get(key, fingerprint=fp)
-                    if entry is None:
-                        value = _read_host(env, payload.in_atoms[ra.ai])
-                        entry = rs.pin(
-                            key,
-                            self._build_resident_pack(payload, ra, value),
-                            fingerprint=fp, aux=keep)
-                    rmap[ra.ai] = entry.pack
-            self._run_region(payload, env, resident_map=rmap)
+            with jax.profiler.TraceAnnotation("cim.region",
+                                              region=payload.index):
+                rmap = None
+                if resident_on and payload.resident:
+                    rmap = {}
+                    for ra in payload.resident:
+                        key = ("lowered", id(self), payload.index,
+                               ra.ai) + fp
+                        entry = rs.get(key, fingerprint=fp)
+                        if entry is None:
+                            value = _read_host(env, payload.in_atoms[ra.ai])
+                            entry = rs.pin(
+                                key,
+                                self._build_resident_pack(payload, ra,
+                                                          value),
+                                fingerprint=fp, aux=keep)
+                        rmap[ra.ai] = entry.pack
+                self._run_region(payload, env, resident_map=rmap)
         outs = [_read_host(env, v) for v in self.trace.closed.jaxpr.outvars]
         out_tree = jax.tree_util.tree_structure(self.trace.out_shape)
         return jax.tree_util.tree_unflatten(out_tree, outs)
-
-    __call__ = execute
 
     def _run_host(self, op: TracedOp, env: Dict[Any, Any]) -> None:
         if op.name == "_alias":
@@ -692,10 +733,14 @@ class LoweredComputation:
         # CPU jit ignores donations with a warning, so skip it there
         donate = donatable \
             if jax.default_backend() in ("gpu", "tpu") else ()
+        # the program is cached by structure, not by name: structurally
+        # identical regions (of other layers, or of another lowered function)
+        # share the program and the name of the one that compiled it
         outs = macro.run_schedule_program(
             schedule, body, leaves,
             body_key=body_key, backend=self.backend,
-            spec=self.spec, mesh=self.mesh, donate=donate)
+            spec=self.spec, mesh=self.mesh, donate=donate,
+            name=f"cim_{self.name}_r{region.index}")
         for var, val in zip(region.unpack_vars, outs):
             env[var] = val
 
@@ -896,8 +941,11 @@ class LoweredFunction:
                  spec: Optional[ArraySpec] = None, mesh=None,
                  resident_argnums: Tuple[int, ...] = (),
                  resident_set=None, policy: Optional[str] = None,
-                 device=None):
+                 device=None, name: Optional[str] = None):
         self.fn = fn
+        # an identifier: it names the region programs (`cim_<name>_r<i>`)
+        self.name = re.sub(r"\W+", "_", name or getattr(fn, "__name__", "")
+                           ).strip("_") or "fn"
         self.backend = backend
         self.spec = spec
         self.mesh = mesh
@@ -940,7 +988,7 @@ class LoweredFunction:
                 spec=self.spec, mesh=self.mesh,
                 resident_leaf_idx=self._resident_leaf_idx(args),
                 resident_set=self.resident_set, policy=self.policy,
-                device=self.device)
+                device=self.device, name=self.name)
             self._cache[key] = comp
             while len(self._cache) > SIGNATURE_CACHE_CAPACITY:
                 self._cache.popitem(last=False)
@@ -956,7 +1004,7 @@ def lower(fn, backend: Optional[str] = None,
           spec: Optional[ArraySpec] = None, mesh=None,
           resident_argnums: Tuple[int, ...] = (),
           resident_set=None, policy: Optional[str] = None,
-          device=None) -> LoweredFunction:
+          device=None, name: Optional[str] = None) -> LoweredFunction:
     """Compile `fn` into a hybrid CiM/host callable (see module docstring).
 
     backend : CiM backend name for the fused regions (registry default
@@ -980,8 +1028,10 @@ def lower(fn, backend: Optional[str] = None,
               pre-cost-model behavior bit-exactly; "never" demotes all.
     device  : DeviceSpec for the host side of the comparison
               (cost.DEFAULT_DEVICE — a v5e chip — when None).
+    name    : what its `cim.call` spans and region programs are called
+              (`fn.__name__` when None; non-word characters become `_`).
     """
     return LoweredFunction(fn, backend=backend, spec=spec, mesh=mesh,
                            resident_argnums=resident_argnums,
                            resident_set=resident_set, policy=policy,
-                           device=device)
+                           device=device, name=name)

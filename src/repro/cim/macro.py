@@ -201,7 +201,7 @@ def _leaf_sig(x):
 def run_schedule_program(schedule: planner.Schedule, body, operands,
                          body_key=(), backend: Optional[str] = None,
                          spec: Optional[ArraySpec] = None, mesh=None,
-                         donate: Tuple[int, ...] = ()):
+                         donate: Tuple[int, ...] = (), name: str = "fn"):
     """Execute `body(cursor, *operands)` as ONE jitted XLA program.
 
     The whole schedule — every planned access plus the zero-cost
@@ -218,6 +218,16 @@ def run_schedule_program(schedule: planner.Schedule, body, operands,
     `donate` names operand leaf positions whose buffers the program may
     reuse for an output of the same shape and dtype (jit donate_argnums);
     callers must only donate buffers that are dead after the call.
+
+    `name` names the jitted function, so the program appears as
+    `jit_<name>` in a profile. It is not part of the key: a cache hit
+    runs the program under the name it was compiled with.
+
+    Inside the program, named scopes mark the work a profile should tell
+    apart: `cim.layout` (a matmul's broadcast operand layout),
+    `cim.multiply` and `cim.reduce` (the shift-and-add multiply and the
+    tree reduction, plane and element shifts included), `cim.kernel` (the
+    fused Pallas call) and `cim.kernel_pad` (its own pad and slice).
 
     Residency note: a cached program keeps its body closure (for a region:
     the Region and any closed-over ConstVal constants) alive until LRU
@@ -249,6 +259,7 @@ def run_schedule_program(schedule: planner.Schedule, body, operands,
         cur.finish()
         return out
 
+    fn.__name__ = name
     jitted = jax.jit(fn, donate_argnums=tuple(donate))
     out = jitted(*leaves)       # first call traces: `charges` fills here
     planned = PlannedCharges(tuple(charges))
@@ -305,6 +316,7 @@ def _plane_mask(bitmap: jax.Array, n_bits: int, like: PlanePack) -> PlanePack:
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("cim.multiply")
 def _multiply_with(cur: ScheduleCursor, a: PlanePack,
                    b: PlanePack) -> PlanePack:
     """Shift-and-add over a cursor (shared by multiply and matmul)."""
@@ -440,6 +452,7 @@ def popcount(a: PlanePack, backend: Optional[str] = None,
                                 spec=spec, mesh=mesh)
 
 
+@jax.named_scope("cim.reduce")
 def _reduce_with(cur: ScheduleCursor, acc: PlanePack,
                  n_steps: Optional[int] = None) -> PlanePack:
     """Log-stride reduction: each planned step shifts the row buffer by its
@@ -513,9 +526,10 @@ def matmul_rhs_pack(b: jax.Array, m: int, n_bits: int,
         raise CimOpError(f"matmul rhs must be [K, N], got {b.shape}")
     k, n = b.shape
     k_pad = 1 << planner._log2_ceil(k)
-    b_exp = jnp.zeros((m, k_pad, n), jnp.int32).at[:, :k, :].set(
-        jnp.broadcast_to(b[None, :, :], (m, k, n)).astype(jnp.int32))
-    return PlanePack.pack(b_exp, n_bits, signed=signed)
+    with jax.named_scope("cim.layout"):
+        b_exp = jnp.zeros((m, k_pad, n), jnp.int32).at[:, :k, :].set(
+            jnp.broadcast_to(b[None, :, :], (m, k, n)).astype(jnp.int32))
+        return PlanePack.pack(b_exp, n_bits, signed=signed)
 
 
 @_one_program
@@ -536,10 +550,11 @@ def batched_matmul_rhs_pack(b: jax.Array, m: int, n_bits: int,
         bf *= int(d)
     k_pad = 1 << planner._log2_ceil(k)
     b3 = b.reshape(bf, k, n)
-    b_exp = jnp.zeros((bf * m, k_pad, n), jnp.int32).at[:, :k, :].set(
-        jnp.broadcast_to(b3[:, None, :, :], (bf, m, k, n))
-        .astype(jnp.int32).reshape(bf * m, k, n))
-    return PlanePack.pack(b_exp, n_bits, signed=signed)
+    with jax.named_scope("cim.layout"):
+        b_exp = jnp.zeros((bf * m, k_pad, n), jnp.int32).at[:, :k, :].set(
+            jnp.broadcast_to(b3[:, None, :, :], (bf, m, k, n))
+            .astype(jnp.int32).reshape(bf * m, k, n))
+        return PlanePack.pack(b_exp, n_bits, signed=signed)
 
 
 def _batched_matmul_with(cur: ScheduleCursor, a: jax.Array, b,
@@ -585,14 +600,18 @@ def _batched_matmul_with(cur: ScheduleCursor, a: jax.Array, b,
         n = int(b.shape[-1])
         k_pad = 1 << planner._log2_ceil(k)
         b3 = b.reshape(bf, k, n)
-        b_exp = jnp.zeros((bf * m, k_pad, n), jnp.int32).at[:, :k, :].set(
-            jnp.broadcast_to(b3[:, None, :, :], (bf, m, k, n))
-            .astype(jnp.int32).reshape(bf * m, k, n))
-        pb = PlanePack.pack(b_exp, n_bits, signed=signed)
+        with jax.named_scope("cim.layout"):
+            b_exp = jnp.zeros((bf * m, k_pad, n), jnp.int32).at[
+                :, :k, :].set(
+                jnp.broadcast_to(b3[:, None, :, :], (bf, m, k, n))
+                .astype(jnp.int32).reshape(bf * m, k, n))
+            pb = PlanePack.pack(b_exp, n_bits, signed=signed)
         cur.charge_load(n_bits, pb.n_words)
-    a_exp = jnp.zeros((bf * m, k_pad, n), jnp.int32).at[:, :k, :].set(
-        jnp.broadcast_to(a2[:, :, None], (bf * m, k, n)).astype(jnp.int32))
-    pa = PlanePack.pack(a_exp, n_bits, signed=signed)
+    with jax.named_scope("cim.layout"):
+        a_exp = jnp.zeros((bf * m, k_pad, n), jnp.int32).at[:, :k, :].set(
+            jnp.broadcast_to(a2[:, :, None], (bf * m, k, n))
+            .astype(jnp.int32))
+        pa = PlanePack.pack(a_exp, n_bits, signed=signed)
     cur.charge_load(n_bits, pa.n_words)
     if b_pack is not None:
         cur.charge_resident(n_bits, pb.n_words)
@@ -636,13 +655,15 @@ def _matmul_with(cur: ScheduleCursor, a: jax.Array, b,
         m, k = a.shape
         n = b.shape[1]
         k_pad = 1 << planner._log2_ceil(k)
-        b_exp = jnp.zeros((m, k_pad, n), jnp.int32).at[:, :k, :].set(
-            jnp.broadcast_to(b[None, :, :], (m, k, n)).astype(jnp.int32))
-        pb = PlanePack.pack(b_exp, n_bits, signed=signed)
+        with jax.named_scope("cim.layout"):
+            b_exp = jnp.zeros((m, k_pad, n), jnp.int32).at[:, :k, :].set(
+                jnp.broadcast_to(b[None, :, :], (m, k, n)).astype(jnp.int32))
+            pb = PlanePack.pack(b_exp, n_bits, signed=signed)
         cur.charge_load(n_bits, pb.n_words)
-    a_exp = jnp.zeros((m, k_pad, n), jnp.int32).at[:, :k, :].set(
-        jnp.broadcast_to(a[:, :, None], (m, k, n)).astype(jnp.int32))
-    pa = PlanePack.pack(a_exp, n_bits, signed=signed)
+    with jax.named_scope("cim.layout"):
+        a_exp = jnp.zeros((m, k_pad, n), jnp.int32).at[:, :k, :].set(
+            jnp.broadcast_to(a[:, :, None], (m, k, n)).astype(jnp.int32))
+        pa = PlanePack.pack(a_exp, n_bits, signed=signed)
     cur.charge_load(n_bits, pa.n_words)
     if b_pack is not None:
         cur.charge_resident(n_bits, pb.n_words)

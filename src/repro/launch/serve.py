@@ -13,10 +13,19 @@ request starts without draining the batch.
 
 Timing discipline: every prefill and decode step is bracketed by
 `jax.block_until_ready` + perf_counter, so a step's latency is the real
-device time, not dispatch time. Steady-state tok/s and the p50/p99
-per-token latencies EXCLUDE prefill and the first `--warmup-steps` decode
-steps (compile happens there); prefill cost is reported separately per
-request (`prefill_ms`).
+device time, not dispatch time. Steady-state tok/s EXCLUDES prefill and
+the first `--warmup-steps` decode steps (compile happens there); prefill
+cost is reported separately per request (`prefill_ms`). Each request
+records when its prefill began (`admit_s`) and when the engine had each
+of its tokens on the host (`token_s`), on the engine's clock; p50/p99 are
+over the gaps between a request's consecutive tokens, as its client sees
+them (a prefill run between two decode steps lies inside the gap).
+
+Each phase of the loop runs under a profiler span (`serve.admit`,
+`serve.prefill` with the request id, `serve.insert`, `serve.decode` with
+the step number and the `host_reads` so far, `serve.sample`, `serve.emit`,
+`serve.wait`), on the clock of the profile's device plane. `host_reads`
+counts the device-to-host reads that block the loop: one per token.
 
 Charge semantics with --cim-lower: prefill and decode steps run UNJITTED
 (the grouped-layer scan is unrolled, see ArchConfig.cim_unroll_groups) so
@@ -75,7 +84,8 @@ class ServeRequest:
     done_s: float = -1.0
     accesses: float = 0.0          # ledger attribution (see module docstring)
     load_accesses: float = 0.0
-    token_latencies_ms: List[float] = dataclasses.field(default_factory=list)
+    admit_s: float = -1.0          # its prefill began
+    token_s: List[float] = dataclasses.field(default_factory=list)
     shed: bool = False             # dropped by admission control, never ran
     repairs: int = 0               # retried decode steps attributed here
 
@@ -90,6 +100,9 @@ class ServeRequest:
             "first_token_s": round(self.first_token_s, 6),
             "done_s": round(self.done_s, 6),
             "prefill_ms": round(self.prefill_ms, 3),
+            # from arrival to the start of its prefill (None: never ran)
+            "queue_ms": round((self.admit_s - self.arrival_s) * 1e3, 3)
+            if self.admit_s >= 0 else None,
             "tokens": len(self.tokens),
             # the generated ids themselves: what the chaos harness compares
             # bit-exactly against a fault-free run
@@ -145,6 +158,7 @@ class ServeEngine:
         self.repairs = 0                      # uncorrectable -> re-pin+retry
         self.failovers = 0                    # bank-kill remaps executed
         self.shed_count = 0
+        self.host_reads = 0                   # blocking device->host reads
         self.scrub_report = {"scanned": 0, "dropped": 0,
                              "corrected": 0, "uncorrected": 0}
         pre, dec = make_prefill_step(model, max_len), make_decode_step(model)
@@ -254,6 +268,7 @@ class ServeEngine:
 
     def run(self, requests: List[ServeRequest]) -> Dict[str, Any]:
         led = _ledger()
+        span = jax.profiler.TraceAnnotation
         pending = deque(sorted(requests, key=lambda r: (r.arrival_s, r.rid)))
         active: Dict[int, ServeRequest] = {}
         free = list(range(self.slots))
@@ -263,7 +278,6 @@ class ServeEngine:
         decode_steps = 0
         steady_tokens = 0
         steady_time = 0.0
-        token_lat_ms: List[float] = []
         t0 = time.perf_counter()
 
         def now() -> float:
@@ -277,62 +291,73 @@ class ServeEngine:
         while pending or active:
             self._check_faults(decode_steps)
 
-            # admission control: shed the head when it has waited past the
-            # per-request timeout, and the tail when more requests are due
-            # than the bounded queue admits — a degraded array sheds load
-            # instead of stretching every in-flight request's latency
-            if self.timeout_s is not None and not free:
-                # only a request actually stuck waiting can time out — a
-                # due head with a free slot is admitted this iteration
-                while pending and pending[0].arrival_s <= now() \
-                        and now() - pending[0].arrival_s > self.timeout_s:
-                    _shed(pending.popleft())
-            if self.queue_limit is not None:
-                # the bounded queue holds what cannot go straight into a
-                # slot: shed the tail past `free slots + queue_limit`
-                while sum(1 for r in pending
-                          if r.arrival_s <= now()) - len(free) \
-                        > self.queue_limit:
-                    _shed(pending.pop())
+            with span("serve.admit"):
+                # admission control: shed the head when it has waited past
+                # the per-request timeout, and the tail when more requests
+                # are due than the bounded queue admits — a degraded array
+                # sheds load instead of stretching every in-flight
+                # request's latency
+                if self.timeout_s is not None and not free:
+                    # only a request actually stuck waiting can time out —
+                    # a due head with a free slot is admitted this iteration
+                    while pending and pending[0].arrival_s <= now() \
+                            and now() - pending[0].arrival_s > self.timeout_s:
+                        _shed(pending.popleft())
+                if self.queue_limit is not None:
+                    # the bounded queue holds what cannot go straight into
+                    # a slot: shed the tail past `free slots + queue_limit`
+                    while sum(1 for r in pending
+                              if r.arrival_s <= now()) - len(free) \
+                            > self.queue_limit:
+                        _shed(pending.pop())
 
-            # admit at most one due request per iteration: prefill
-            # interleaves with decode instead of draining the batch
-            if pending and free and pending[0].arrival_s <= now():
-                req = pending[0]
-                if self.paged is not None and \
-                        not self.paged.alloc(req.rid, req.prompt_len):
-                    if not active:
-                        raise RuntimeError(
-                            f"request {req.rid}: prompt of {req.prompt_len} "
-                            f"tokens cannot fit the KV block pool even with "
-                            f"every slot idle")
-                    # pool pressure: wait for a retirement to free blocks
-                else:
-                    pending.popleft()
-                    slot = free.pop(0)
-                    req.slot = slot
+                # admit at most one due request per iteration: prefill
+                # interleaves with decode instead of draining the batch
+                req = None
+                if pending and free and pending[0].arrival_s <= now():
+                    head = pending[0]
+                    if self.paged is not None and \
+                            not self.paged.alloc(head.rid, head.prompt_len):
+                        if not active:
+                            raise RuntimeError(
+                                f"request {head.rid}: prompt of "
+                                f"{head.prompt_len} tokens cannot fit the KV "
+                                f"block pool even with every slot idle")
+                        # pool pressure: wait for a retirement to free blocks
+                    else:
+                        req = pending.popleft()
+                        req.slot = free.pop(0)
+
+            if req is not None:
+                slot = req.slot
+                with span("serve.prefill", rid=req.rid):
                     ta = time.perf_counter()
+                    req.admit_s = ta - t0
                     l0 = (led.accesses, led.load_accesses)
                     c1, logits1 = self.prefill_fn(self.params,
                                                   self._prompt_inputs(req))
                     jax.block_until_ready(logits1)
                     req.prefill_ms = (time.perf_counter() - ta) * 1e3
-                    req.accesses += led.accesses - l0[0]
-                    req.load_accesses += led.load_accesses - l0[1]
+                req.accesses += led.accesses - l0[0]
+                req.load_accesses += led.load_accesses - l0[1]
+                with span("serve.insert"):
                     caches = self._insert(caches, c1, slot)
                     first = self.sample(logits1)[0]
                     tok = tok.at[slot].set(first)
                     req.tokens.append(int(first))
+                    self.host_reads += 1
                     req.first_token_s = now()
+                    req.token_s.append(req.first_token_s)
                     positions[slot] = req.prompt_len
                     active[slot] = req
                     if req.done:                       # gen == 1
                         self._retire(req, free, active, now())
-                    continue                           # admit before decode
+                continue                               # admit before decode
 
             if not active:
                 if pending:
-                    time.sleep(max(0.0, pending[0].arrival_s - now()))
+                    with span("serve.wait"):
+                        time.sleep(max(0.0, pending[0].arrival_s - now()))
                 continue
 
             # one full-batch decode step — retried within the per-request
@@ -341,45 +366,47 @@ class ServeEngine:
             # from the host weights: detect -> repair -> redo)
             from repro.cim.faults import UncorrectableFaultError
 
-            step_in = self._step_inputs(tok, positions, decode_steps)
-            ts = time.perf_counter()
-            l0 = (led.accesses, led.load_accesses)
-            attempts = 0
-            while True:
-                try:
-                    caches, logits = self.decode_fn(self.params, caches,
-                                                    step_in)
-                    break
-                except UncorrectableFaultError:
-                    attempts += 1
-                    self.repairs += 1
-                    for req in active.values():
-                        req.repairs += 1
-                    if attempts > self.retry_budget:
-                        raise
-            jax.block_until_ready((caches, logits))
-            dt = time.perf_counter() - ts
+            with span("serve.decode", step=decode_steps,
+                      host_reads=self.host_reads):
+                step_in = self._step_inputs(tok, positions, decode_steps)
+                ts = time.perf_counter()
+                l0 = (led.accesses, led.load_accesses)
+                attempts = 0
+                while True:
+                    try:
+                        caches, logits = self.decode_fn(self.params, caches,
+                                                        step_in)
+                        break
+                    except UncorrectableFaultError:
+                        attempts += 1
+                        self.repairs += 1
+                        for req in active.values():
+                            req.repairs += 1
+                        if attempts > self.retry_budget:
+                            raise
+                jax.block_until_ready((caches, logits))
+                dt = time.perf_counter() - ts
             d_acc = led.accesses - l0[0]
             d_load = led.load_accesses - l0[1]
-            tok = self.sample(logits)
+            with span("serve.sample"):
+                tok = self.sample(logits)
             n_active = len(active)
             decode_steps += 1
-            steady = decode_steps > self.warmup_steps
-            if steady:
+            if decode_steps > self.warmup_steps:
                 steady_tokens += n_active
                 steady_time += dt
-            for slot, req in list(active.items()):
-                req.tokens.append(int(tok[slot]))
-                req.accesses += d_acc / n_active
-                req.load_accesses += d_load / n_active
-                req.token_latencies_ms.append(dt * 1e3)
-                if steady:
-                    token_lat_ms.append(dt * 1e3)
-                positions[slot] += 1
-                if self.paged is not None:
-                    self.paged.extend(req.rid)
-                if req.done:
-                    self._retire(req, free, active, now())
+            with span("serve.emit"):
+                for slot, req in list(active.items()):
+                    req.tokens.append(int(tok[slot]))
+                    self.host_reads += 1
+                    req.token_s.append(now())
+                    req.accesses += d_acc / n_active
+                    req.load_accesses += d_load / n_active
+                    positions[slot] += 1
+                    if self.paged is not None:
+                        self.paged.extend(req.rid)
+                    if req.done:
+                        self._retire(req, free, active, now())
             if self.scrub_every and decode_steps % self.scrub_every == 0:
                 self._scrub()
 
@@ -387,6 +414,8 @@ class ServeEngine:
         # first token of each SERVED request comes from its prefill (shed
         # requests produced nothing, so an all-shed run reports 0, not -n)
         decode_tokens = sum(max(0, len(r.tokens) - 1) for r in requests)
+        gaps_ms = [(b - a) * 1e3 for r in requests
+                   for a, b in zip(r.token_s, r.token_s[1:])]
         report: Dict[str, Any] = {
             "slots": self.slots,
             "requests": len(requests),
@@ -398,12 +427,13 @@ class ServeEngine:
             "tok_s_steady": round(steady_tokens / steady_time, 2)
             if steady_time > 0 else 0.0,
             "steady_tokens": steady_tokens,
-            "p50_ms": round(_percentile(token_lat_ms, 50), 3),
-            "p99_ms": round(_percentile(token_lat_ms, 99), 3),
+            "p50_ms": round(_percentile(gaps_ms, 50), 3),
+            "p99_ms": round(_percentile(gaps_ms, 99), 3),
             "prefill_ms_mean": round(
                 sum(r.prefill_ms for r in requests) / max(1, len(requests)),
                 3),
             "shed": self.shed_count,
+            "host_reads": self.host_reads,
             "completed": sum(1 for r in requests
                              if not r.shed and r.done),
             "per_request": [r.report() for r in requests],
@@ -546,7 +576,8 @@ def _print_cim_report(tag: str) -> None:
           f"(current sensing @1024^2)")
     cs = cache_stats()
     print(f"  schedule cache: {cs['hits']} hits / {cs['misses']} misses, "
-          f"{cs['dispatches']} jitted dispatches; resident: "
+          f"{cs['dispatches']} jitted dispatches, {cs['host_eqns']} host "
+          f"eqns; resident: "
           f"{cs.get('resident_pins', 0)} pins / "
           f"{cs.get('resident_hits', 0)} hits / "
           f"{cs.get('resident_evictions', 0)} evictions, "

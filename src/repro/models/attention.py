@@ -114,7 +114,7 @@ def _lowered_sdpa(n_bits: int, backend, spec, mesh, resident: bool = False,
             backend=backend, spec=spec, mesh=mesh,
             resident_argnums=(1, 2) if resident else (),
             resident_set=array.resident_set(spec) if resident else None,
-            policy=policy))
+            policy=policy, name="sdpa"))
 
 
 def sdpa_cim(q, k, v, mask, scale, n_bits: int = 8,
@@ -247,23 +247,29 @@ def gqa_decode_cim(p, cfg: ArchConfig, x, cache: Params, positions
     functional cache update makes a fresh array per token, so identity-
     fingerprinted resident pins would churn, never hit (resident KV reuse
     is exercised where the arrays are stable: `sdpa_cim(resident=True)`
-    with a fixed cache, as in the bench's attention section)."""
+    with a fixed cache, as in the bench's attention section). Its host
+    phases run under `model.qkv`, `model.kv_write` and `model.out_proj`
+    spans."""
     from .moe import _hint
 
-    pos2 = positions[:, None]
-    q, k, v = _gqa_qkv(p, cfg, x, pos2)
-    q = _hint(q, ("DP", None, None, "model"))
-    k = _hint(k, ("DP", None, None, "model"))
-    v = _hint(v, ("DP", None, None, "model"))
-    bidx = jnp.arange(x.shape[0])
-    ck = cache["k"].at[bidx, positions].set(k[:, 0])
-    cv = cache["v"].at[bidx, positions].set(v[:, 0])
-    t_max = ck.shape[1]
-    valid = jnp.arange(t_max)[None, :] <= positions[:, None]
+    span = jax.profiler.TraceAnnotation
+    with span("model.qkv"):           # projections, qk-norm and rotary
+        pos2 = positions[:, None]
+        q, k, v = _gqa_qkv(p, cfg, x, pos2)
+        q = _hint(q, ("DP", None, None, "model"))
+        k = _hint(k, ("DP", None, None, "model"))
+        v = _hint(v, ("DP", None, None, "model"))
+    with span("model.kv_write"):
+        bidx = jnp.arange(x.shape[0])
+        ck = cache["k"].at[bidx, positions].set(k[:, 0])
+        cv = cache["v"].at[bidx, positions].set(v[:, 0])
+        t_max = ck.shape[1]
+        valid = jnp.arange(t_max)[None, :] <= positions[:, None]
     o = sdpa_cim(q, ck, cv, valid[:, None, :], 1.0 / cfg.head_dim ** 0.5,
                  n_bits=cfg.cim_attention_bits, policy=cfg.cim_policy)
-    y = jnp.einsum("bthk,hkd->btd", o, p["wo"],
-                   preferred_element_type=jnp.float32).astype(x.dtype)
+    with span("model.out_proj"):
+        y = jnp.einsum("bthk,hkd->btd", o, p["wo"],
+                       preferred_element_type=jnp.float32).astype(x.dtype)
     return y, {"k": ck, "v": cv}
 
 

@@ -218,7 +218,7 @@ def blockwise_attention_cim(q, k, v, causal=True, scale=None, window=0,
                      backend=backend, spec=spec, mesh=mesh,
                      resident_argnums=(1,) if resident else (),
                      resident_set=array.resident_set(spec)
-                     if resident else None)
+                     if resident else None, name="attn_bmm")
 
     bmm = _lru_get(_LOWERED_BMM, (n_bits, backend, spec, mesh, resident),
                    make)

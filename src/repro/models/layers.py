@@ -231,7 +231,8 @@ def _lowered_linear(n_bits: int, backend, spec, mesh, resident: bool = False):
         _LOWERED_LINEAR, (n_bits, backend, spec, mesh, resident),
         lambda: lower(lambda x, w: _quantized_linear(x, w, n_bits),
                       backend=backend, spec=spec, mesh=mesh,
-                      resident_argnums=(1,) if resident else ()))
+                      resident_argnums=(1,) if resident else (),
+                      name="linear"))
 
 
 def _lowered_mlp(gating: str, n_bits: int, backend, spec, mesh,
@@ -243,7 +244,7 @@ def _lowered_mlp(gating: str, n_bits: int, backend, spec, mesh,
         lambda: lower(lambda p, x: _mlp_quantized(p, x, gating, n_bits),
                       backend=backend, spec=spec, mesh=mesh,
                       resident_argnums=(0,) if resident else (),
-                      policy=policy))
+                      policy=policy, name="mlp"))
 
 
 def cim_linear(x: jax.Array, w: jax.Array, n_bits: int = 8,

@@ -17,6 +17,13 @@ scan step applies one full period (P heterogeneous layers), so heterogeneous
 stacks (rec,rec,local / m,m,m,s) still compile as a single rolled loop.
 
 Caches mirror the same structure; every cache/state is a plain pytree.
+
+The phases of a step run under profiler spans (`model.embed`,
+`model.layer` holding `model.cast`, `model.norm`, `model.attn` and
+`model.mlp`, `model.cache_slice` and `model.cache_stack` in an unrolled
+stack, `model.head`), on the clock of the profile's device plane. Inside a
+jitted step they mark tracing alone; in an unjitted (CiM-lowered) step
+they name what the host runs between the lowered calls.
 """
 from __future__ import annotations
 
@@ -45,6 +52,10 @@ from .layers import (
 )
 
 Params = Dict[str, Any]
+
+
+def _span(name: str, **kwargs):
+    return jax.profiler.TraceAnnotation(name, **kwargs)
 
 
 # ---------------------------------------------------------------------------
@@ -133,42 +144,32 @@ def _layer_apply(
     cache, max_len: Optional[int],
 ):
     """Returns (x, aux_loss, new_cache)."""
-    p = _compute_cast(p, cfg.activation_dtype())
-    aux = jnp.zeros((), jnp.float32)
-    h = rmsnorm(p["ln1"], x, cfg.norm_eps)
+    with _span("model.layer"):
+        return _layer_phases(p, cfg, kind, layer_idx, x, positions, mode,
+                             cache, max_len)
+
+
+def _layer_phases(p, cfg, kind, layer_idx, x, positions, mode, cache,
+                  max_len):
+    with _span("model.cast"):
+        p = _compute_cast(p, cfg.activation_dtype())
+        aux = jnp.zeros((), jnp.float32)
+    with _span("model.norm"):
+        h = rmsnorm(p["ln1"], x, cfg.norm_eps)
 
     if kind in ("attn", "local"):
-        if cfg.mla:
-            if mode == "train":
-                y, new_cache = attn.mla_apply(p["attn"], cfg, h, positions), None
-            elif mode == "prefill":
-                y, new_cache = attn.mla_prefill(p["attn"], cfg, h, positions, max_len)
+        with _span("model.attn"):
+            y, new_cache = _attn_apply(p, cfg, kind, h, positions, mode,
+                                       cache, max_len)
+            x = x + y
+        with _span("model.norm"):
+            h2 = rmsnorm(p["ln2"], x, cfg.norm_eps)
+        with _span("model.mlp"):
+            if cfg.moe is not None and layer_idx >= cfg.first_dense_layers:
+                y2, aux = moe_lib.moe_apply(p["mlp"], cfg, h2)
             else:
-                y, new_cache = attn.mla_decode(p["attn"], cfg, h, cache, positions)
-        elif kind == "local":
-            if mode == "train":
-                y, new_cache = attn.local_apply(p["attn"], cfg, h, positions), None
-            elif mode == "prefill":
-                y, new_cache = attn.local_prefill(p["attn"], cfg, h, positions)
-            else:
-                y, new_cache = attn.local_decode(p["attn"], cfg, h, cache, positions)
-        else:
-            if mode == "train":
-                y, new_cache = attn.gqa_apply(p["attn"], cfg, h, positions), None
-            elif mode == "prefill":
-                y, new_cache = attn.gqa_prefill(p["attn"], cfg, h, positions, max_len)
-            elif cfg.cim_attention_bits:
-                y, new_cache = attn.gqa_decode_cim(p["attn"], cfg, h, cache,
-                                                   positions)
-            else:
-                y, new_cache = attn.gqa_decode(p["attn"], cfg, h, cache, positions)
-        x = x + y
-        h2 = rmsnorm(p["ln2"], x, cfg.norm_eps)
-        if cfg.moe is not None and layer_idx >= cfg.first_dense_layers:
-            y2, aux = moe_lib.moe_apply(p["mlp"], cfg, h2)
-        else:
-            y2 = _apply_mlp(cfg, p["mlp"], h2, mode)
-        return x + y2, aux, new_cache
+                y2 = _apply_mlp(cfg, p["mlp"], h2, mode)
+            return x + y2, aux, new_cache
 
     if kind == "rec":
         state = cache if mode == "decode" else None
@@ -187,6 +188,29 @@ def _layer_apply(
         return x + y, aux, new_cache
 
     raise ValueError(kind)
+
+
+def _attn_apply(p, cfg, kind, h, positions, mode, cache, max_len):
+    """The attention of one "attn" or "local" layer: (y, new_cache)."""
+    if cfg.mla:
+        if mode == "train":
+            return attn.mla_apply(p["attn"], cfg, h, positions), None
+        if mode == "prefill":
+            return attn.mla_prefill(p["attn"], cfg, h, positions, max_len)
+        return attn.mla_decode(p["attn"], cfg, h, cache, positions)
+    if kind == "local":
+        if mode == "train":
+            return attn.local_apply(p["attn"], cfg, h, positions), None
+        if mode == "prefill":
+            return attn.local_prefill(p["attn"], cfg, h, positions)
+        return attn.local_decode(p["attn"], cfg, h, cache, positions)
+    if mode == "train":
+        return attn.gqa_apply(p["attn"], cfg, h, positions), None
+    if mode == "prefill":
+        return attn.gqa_prefill(p["attn"], cfg, h, positions, max_len)
+    if cfg.cim_attention_bits:
+        return attn.gqa_decode_cim(p["attn"], cfg, h, cache, positions)
+    return attn.gqa_decode(p["attn"], cfg, h, cache, positions)
 
 
 # ---------------------------------------------------------------------------
@@ -358,13 +382,15 @@ class Model:
                 slices = (params["group_layers"] if unstacked else
                           self._group_param_slices(params["groups"]))
                 for g, gp in enumerate(slices):
-                    gc = (jax.tree.map(lambda a: a[g], caches["groups"])
-                          if caches is not None else None)
+                    with _span("model.cache_slice"):
+                        gc = (jax.tree.map(lambda a: a[g], caches["groups"])
+                              if caches is not None else None)
                     carry, ncs = body(carry, (gp, gc))
                     ncs_stacked.append(ncs)
                 x, aux_total = carry
-                new_caches["groups"] = jax.tree.map(
-                    lambda *xs_: jnp.stack(xs_), *ncs_stacked)
+                with _span("model.cache_stack"):
+                    new_caches["groups"] = jax.tree.map(
+                        lambda *xs_: jnp.stack(xs_), *ncs_stacked)
             else:
                 xs = (params["groups"],
                       caches["groups"] if caches is not None else None)
@@ -380,7 +406,8 @@ class Model:
             aux_total += aux
             new_caches["rem"].append(nc)
 
-        x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        with _span("model.norm"):
+            x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
         return x, aux_total, new_caches
 
     def _group_param_slices(self, groups):
@@ -449,20 +476,24 @@ class Model:
 
     def prefill(self, params, inputs, max_len: int):
         """Returns (caches, last_token_logits [B, V])."""
-        x = self._embed_inputs(params, inputs)
-        positions = self._positions(inputs, x, "prefill")
+        with _span("model.embed"):
+            x = self._embed_inputs(params, inputs)
+            positions = self._positions(inputs, x, "prefill")
         x, _, caches = self._run_stack(params, x, positions, "prefill",
                                        caches=None, max_len=max_len)
-        return caches, self.logits(params, x[:, -1:])[:, 0]
+        with _span("model.head"):
+            return caches, self.logits(params, x[:, -1:])[:, 0]
 
     def decode_step(self, params, caches, inputs):
         """One token step. inputs: tokens/embeds [B,1] + positions [B].
         Returns (new_caches, logits [B, V])."""
-        x = self._embed_inputs(params, inputs)
+        with _span("model.embed"):
+            x = self._embed_inputs(params, inputs)
         positions = inputs["positions"]
         x, _, new_caches = self._run_stack(params, x, positions, "decode",
                                            caches=caches)
-        return new_caches, self.logits(params, x)[:, 0]
+        with _span("model.head"):
+            return new_caches, self.logits(params, x)[:, 0]
 
 
 def build(cfg: ArchConfig) -> Model:

@@ -140,7 +140,7 @@ def test_engine_completes_all_requests(small_model):
         assert r.first_token_s >= r.arrival_s
         assert r.done_s >= r.first_token_s
         assert r.prefill_ms > 0.0
-        assert len(r.token_latencies_ms) == r.gen - 1
+        assert len(r.token_s) == r.gen
     # 3 requests through 2 slots: the third waited for a retirement
     assert {r.slot for r in requests} == {0, 1}
 
@@ -444,3 +444,64 @@ class TestAdmissionControl:
         assert rep["p50_ms"] == 0.0 and rep["p99_ms"] == 0.0
         assert rep["tok_s_steady"] == 0.0
         assert all(r["shed"] for r in rep["per_request"])
+        assert all(r["queue_ms"] is None for r in rep["per_request"])
+
+
+# ---------------------------------------------------------------------------
+# what the engine shows a profile: token timestamps, reads, phase spans
+# ---------------------------------------------------------------------------
+
+
+def test_engine_stamps_every_token_and_counts_host_reads(small_model):
+    model, params = small_model
+    engine = ServeEngine(model, params, slots=2, max_len=7, warmup_steps=0)
+    requests = [ServeRequest(rid=i, prompt_len=4, gen=3) for i in range(3)]
+    rep = engine.run(requests)
+    for r in requests:
+        assert len(r.token_s) == r.gen
+        assert r.token_s == sorted(r.token_s)
+        assert r.admit_s >= r.arrival_s
+        assert r.token_s[0] == r.first_token_s
+        assert r.token_s[-1] <= r.done_s
+    assert [p["queue_ms"] for p in rep["per_request"]] \
+        == [round((r.admit_s - r.arrival_s) * 1e3, 3) for r in requests]
+    # the third request waited for a slot
+    assert rep["per_request"][2]["queue_ms"] > 0
+    # one blocking read a token: the prefill's first and a slot's per step
+    assert engine.host_reads == rep["host_reads"] == rep["total_tokens"] == 9
+    gaps = [(b - a) * 1e3 for r in requests
+            for a, b in zip(r.token_s, r.token_s[1:])]
+    assert rep["p50_ms"] == round(_percentile(gaps, 50), 3)
+
+
+def test_engine_enters_a_span_per_phase(small_model, monkeypatch):
+    seen = []
+
+    class Recording:
+        def __init__(self, name, **kwargs):
+            seen.append((name, kwargs))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recording)
+    model, params = small_model
+    engine = ServeEngine(model, params, slots=1, max_len=7, warmup_steps=0)
+    # the first arrival is not due when the loop starts: it waits first
+    requests = [ServeRequest(rid=5, prompt_len=4, gen=3, arrival_s=0.2),
+                ServeRequest(rid=6, prompt_len=4, gen=2, arrival_s=0.2)]
+    engine.run(requests)
+    # the model's own spans mark the jitted steps' tracing alone
+    names = [n for n, _ in seen if n.startswith("serve.")]
+    assert set(names) == {"serve.admit", "serve.wait", "serve.prefill",
+                          "serve.insert", "serve.decode", "serve.sample",
+                          "serve.emit"}
+    assert names[:2] == ["serve.admit", "serve.wait"]
+    assert [kw["rid"] for n, kw in seen if n == "serve.prefill"] == [5, 6]
+    decodes = [kw for n, kw in seen if n == "serve.decode"]
+    assert [kw["step"] for kw in decodes] == [0, 1, 2]
+    # the counter as each step starts: 1 first token, then 1 a step
+    assert [kw["host_reads"] for kw in decodes] == [1, 2, 4]
