@@ -14,10 +14,12 @@ Two substrate services live here as well:
     WHOLE-schedule step programs built by repro.cim.macro — one jitted XLA
     dispatch covering every access of a macro or fused region. `cache_stats()`
     exposes hit/miss/eviction counters plus `dispatches`, the number of
-    jitted-program invocations — the deterministic walltime proxy the
-    benchmarks gate on (a warm macro matmul is exactly ONE dispatch) — and
+    jitted-program invocations (the deterministic walltime proxy the
+    benchmarks gate on: a warm macro matmul is exactly ONE dispatch),
     `host_eqns`, the eqns the lowering executor bound one at a time on the
-    host (repro.cim.lower).
+    host (repro.cim.lower), and `reduce_fold_steps`/`reduce_shift_steps`,
+    the tree-reduction accesses the step programs ran as a halving fold
+    or as row-buffer shifts (repro.cim.macro).
   * a `jax.shard_map` path over the production/smoke meshes of
     repro.launch.mesh: pass `mesh=` and tiles are block-distributed over the
     mesh's "data" axis, each device executing (and its ledger slice being
@@ -143,6 +145,8 @@ _MISSES = 0
 _EVICTIONS = 0
 _DISPATCHES = 0
 _HOST_EQNS = 0
+_REDUCE_FOLD_STEPS = 0
+_REDUCE_SHIFT_STEPS = 0
 
 
 def cache_stats() -> Dict[str, int]:
@@ -152,14 +156,18 @@ def cache_stats() -> Dict[str, int]:
     alike). A warm macro or fused region costs exactly one dispatch.
     `host_eqns` counts the eqns lowered functions executed on the host,
     each its own eager dispatch (eqns a warm resident call skips are not
-    counted).
+    counted). `reduce_fold_steps` and `reduce_shift_steps` count the
+    tree-reduction accesses step programs executed as a halving fold and
+    as row-buffer shifts, per invocation (eager cursors are not counted).
     Resident-region counters (resident_pins/hits/misses/evictions/
     invalidations, aggregated across every ResidentSet) ride along so one
     call answers both "did the program cache stay warm" and "did the
     operands stay pinned"."""
     stats = {"hits": _HITS, "misses": _MISSES, "entries": len(_PROGRAMS),
              "evictions": _EVICTIONS, "capacity": _CAPACITY,
-             "dispatches": _DISPATCHES, "host_eqns": _HOST_EQNS}
+             "dispatches": _DISPATCHES, "host_eqns": _HOST_EQNS,
+             "reduce_fold_steps": _REDUCE_FOLD_STEPS,
+             "reduce_shift_steps": _REDUCE_SHIFT_STEPS}
     stats.update(array_mod.resident_stats())
     from . import faults as faults_mod
 
@@ -168,13 +176,16 @@ def cache_stats() -> Dict[str, int]:
 
 
 def clear_schedule_cache() -> None:
-    global _HITS, _MISSES, _EVICTIONS, _DISPATCHES, _HOST_EQNS
+    global _HITS, _MISSES, _EVICTIONS, _DISPATCHES, _HOST_EQNS, \
+        _REDUCE_FOLD_STEPS, _REDUCE_SHIFT_STEPS
     _PROGRAMS.clear()
     _HITS = 0
     _MISSES = 0
     _EVICTIONS = 0
     _DISPATCHES = 0
     _HOST_EQNS = 0
+    _REDUCE_FOLD_STEPS = 0
+    _REDUCE_SHIFT_STEPS = 0
 
 
 def count_dispatch(n: int = 1) -> None:
@@ -187,6 +198,14 @@ def count_host_eqns(n: int) -> None:
     """Record `n` eqns executed on the host by a lowered function."""
     global _HOST_EQNS
     _HOST_EQNS += n
+
+
+def count_reduce_steps(fold: int, shift: int) -> None:
+    """Record one program invocation's tree-reduction accesses: `fold` run
+    as a halving fold, `shift` as row-buffer shifts."""
+    global _REDUCE_FOLD_STEPS, _REDUCE_SHIFT_STEPS
+    _REDUCE_FOLD_STEPS += fold
+    _REDUCE_SHIFT_STEPS += shift
 
 
 def program_cache_get(key):

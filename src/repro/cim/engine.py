@@ -54,7 +54,8 @@ def prepare_operands(a: PlanePack, b: PlanePack, ops: Sequence[str]
 
 def execute_traced(a: PlanePack, b: PlanePack, ops: Sequence[str],
                    backend: Optional[str] = None,
-                   charges: Optional[list] = None) -> Outputs:
+                   charges: Optional[list] = None,
+                   n_words: Optional[int] = None) -> Outputs:
     """The side-effect-free inner form of `execute`: pure computation, no
     ledger mutation, so a whole schedule of these can be traced into ONE
     jitted XLA program (repro.cim.macro.run_schedule_program).
@@ -63,12 +64,16 @@ def execute_traced(a: PlanePack, b: PlanePack, ops: Sequence[str],
     charge-from-plan record — ("access", ops, n_bits, n_words) at the
     post-alignment width, exactly what `execute` would have charged — for
     the compiled program to replay per invocation (accounting.PlannedCharges).
+    `n_words` charges the modelled access's words where the caller computes
+    fewer than it (the matmul's halving fold, `macro._fold_reduce`); by
+    default the operands' own words are charged.
     """
     a, b, ops = prepare_operands(a, b, ops)
     bk = get_backend(backend)
     raws = bk(a.planes, b.planes, ops)
     if charges is not None:
-        charges.append(("access", ops, a.n_bits, a.n_words))
+        charges.append(("access", ops, a.n_bits,
+                        a.n_words if n_words is None else n_words))
     return {op: _wrap(op, raw, a.n_bits, a.shape)
             for op, raw in zip(ops, raws)}
 

@@ -40,10 +40,14 @@ Macros:
   dot/matmul — int x int -> wide-int contraction: one multiply over a
                broadcast [M, K_pad, N] layout + a stride-N reduction; the
                access count depends only on the bit width and K (word
-               parallelism), never on M or N
+               parallelism), never on M or N. In a compiled unbanked
+               program the reduction folds in halves and computes only
+               the words its result keeps; the ledger still charges each
+               add at the full row
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Optional, Sequence, Tuple
 
@@ -76,6 +80,11 @@ class ScheduleCursor:
     never touched, and every planned charge is appended to `charges` — the
     charge-from-plan record `run_schedule_program` replays per invocation
     of the compiled step program.
+
+    `fold_steps` and `shift_steps` count the tree-reduction accesses the
+    cursor ran as a halving fold (`_fold_reduce`) and as row-buffer shifts
+    (`_reduce_with`); a compiled program reports them per invocation
+    through `dispatch.cache_stats()`.
     """
 
     def __init__(self, schedule: planner.Schedule,
@@ -88,6 +97,8 @@ class ScheduleCursor:
         self.mesh = mesh
         self.charges = charges
         self._i = 0
+        self.fold_steps = 0
+        self.shift_steps = 0
 
     def step(self) -> planner.Step:
         if self._i >= len(self.schedule.steps):
@@ -96,19 +107,28 @@ class ScheduleCursor:
                 f"{self.schedule.accesses} accesses")
         return self.schedule.steps[self._i]
 
-    def execute(self, a: PlanePack, b: PlanePack,
-                ops: Sequence[str]) -> engine.Outputs:
+    def execute(self, a: PlanePack, b: PlanePack, ops: Sequence[str],
+                charge_words: Optional[int] = None) -> engine.Outputs:
+        """One planned access. `charge_words` (traced unbanked cursors
+        only) charges the modelled access's words where the operands hold
+        fewer: the matmul's halving fold computes only the words its exit
+        keeps, while the array activates the full row."""
         step = self.step()
         if tuple(ops) != step.ops:
             raise CimOpError(
                 f"{self.schedule.macro}: access {self._i} executes {ops!r} "
                 f"but the plan says {step.ops!r}")
+        if charge_words is not None and not self.folds:
+            raise CimOpError(
+                f"{self.schedule.macro}: charge_words needs a traced "
+                f"unbanked cursor")
         self._i += 1
         if self.charges is not None:
             if self.spec is None:
                 return engine.execute_traced(a, b, step.ops,
                                              backend=self.backend,
-                                             charges=self.charges)
+                                             charges=self.charges,
+                                             n_words=charge_words)
             return dispatch.execute_tiled_traced(
                 a, b, step.ops, spec=self.spec, backend=self.backend,
                 mesh=self.mesh, charges=self.charges)
@@ -116,6 +136,16 @@ class ScheduleCursor:
             return engine.execute(a, b, step.ops, backend=self.backend)
         return dispatch.execute_tiled(a, b, step.ops, spec=self.spec,
                                       backend=self.backend, mesh=self.mesh)
+
+    @property
+    def folds(self) -> bool:
+        """Whether a matmul's tree reduction may run as a halving fold:
+        traced (every access a charge-from-plan record, so the charged words
+        need not be the computed ones) and unbanked (a banked reduction's
+        inter-bank charge depends on each shift's stride against the tile
+        boundaries). Eager cursors keep the shifts, where the fault overlay
+        corrupts the streamed operands at their full width."""
+        return self.charges is not None and self.spec is None
 
     def charge_reduction(self, words32: float) -> None:
         """Inter-bank reduction traffic: charged directly in eager mode,
@@ -162,22 +192,31 @@ class CompiledSchedule:
 
     Calling it replays the recorded ledger charges (computed once, at trace
     time, from the cursor-checked plan) and invokes the compiled program —
-    ONE XLA dispatch for the entire schedule."""
+    ONE XLA dispatch for the entire schedule. `reduce_steps` holds the
+    (fold, shift) tree-reduction accesses the trace ran, counted per
+    invocation like the dispatch."""
 
-    __slots__ = ("fn", "charges")
+    __slots__ = ("fn", "charges", "reduce_steps")
 
-    def __init__(self, fn, charges: PlannedCharges):
+    def __init__(self, fn, charges: PlannedCharges,
+                 reduce_steps: Tuple[int, int] = (0, 0)):
         self.fn = fn
         self.charges = charges
+        self.reduce_steps = reduce_steps
 
     def __call__(self, *leaves):
         # invoke first, account after: a failed invocation must not leave
         # the ledger charged (or the dispatch counter bumped) for an
         # execution that never happened
         out = self.fn(*leaves)
+        self.account()
+        return out
+
+    def account(self) -> None:
+        """The per-invocation bookkeeping: charges, dispatch, reductions."""
         self.charges.replay()
         dispatch.count_dispatch()
-        return out
+        dispatch.count_reduce_steps(*self.reduce_steps)
 
 
 def aval_sig(aval) -> Tuple:
@@ -250,6 +289,7 @@ def run_schedule_program(schedule: planner.Schedule, body, operands,
     # implied by an operand's type: a top-level PlanePack may already live
     # in rows, and eager-cursor execution must charge identically
     charges: list = []
+    reduce_steps = [0, 0]
 
     def fn(*flat):
         args = jax.tree_util.tree_unflatten(treedef, list(flat))
@@ -257,6 +297,7 @@ def run_schedule_program(schedule: planner.Schedule, body, operands,
                              charges=charges)
         out = body(cur, *args)
         cur.finish()
+        reduce_steps[:] = [cur.fold_steps, cur.shift_steps]
         return out
 
     fn.__name__ = name
@@ -267,9 +308,9 @@ def run_schedule_program(schedule: planner.Schedule, body, operands,
         raise CimOpError(
             f"{schedule.macro}: traced {planned.accesses} accesses but the "
             f"plan has {schedule.accesses}")
-    dispatch.program_cache_put(key, CompiledSchedule(jitted, planned))
-    planned.replay()
-    dispatch.count_dispatch()
+    prog = CompiledSchedule(jitted, planned, tuple(reduce_steps))
+    dispatch.program_cache_put(key, prog)
+    prog.account()
     return out
 
 
@@ -467,7 +508,10 @@ def _reduce_with(cur: ScheduleCursor, acc: PlanePack,
     On a banked cursor the shift moves words BETWEEN tiles whenever the
     stride reaches across a tile boundary — that movement is the inter-bank
     reduction traffic the ledger charges (fraction of words crossing scales
-    with stride/tile_words, capped at all of them)."""
+    with stride/tile_words, capped at all of them).
+
+    A matmul's reduction on a traced unbanked cursor folds in halves
+    instead (`_fold_reduce`)."""
     if not acc.signed:
         acc = acc.extend_to(acc.n_bits + 1).as_signed(True)
     steps = cur.remaining()
@@ -482,7 +526,101 @@ def _reduce_with(cur: ScheduleCursor, acc: PlanePack,
                     acc.n_words * frac * acc.n_bits / 32.0)
         shifted = acc.shift_elements(step.stride)
         acc = cur.execute(acc, shifted, ("add",))["add"]
+        cur.shift_steps += 1
     return acc
+
+
+@jax.named_scope("cim.reduce")
+def _fold_reduce(cur: ScheduleCursor, prod: PlanePack) -> PlanePack:
+    """A matmul's log2(K_pad) planned adds over the [M', K_pad, N] product
+    as a halving fold: each level adds the upper half of every row's k
+    range onto the lower, acc[:, :H] + acc[:, H:2H] for H = K_pad/2, ...,
+    1, and goes on with the compact [M', H, N] sum. Returns the
+    [M', K_rem, N] pack whose k = 0 slice is the contraction (K_rem = 1
+    where every level folded).
+
+    A level folds while H * N is a whole number of 32-element lane words,
+    so each half is a plain slice of the plane stack; the levels below
+    that (N not a multiple of 32) shift the row buffer as `_reduce_with`
+    does, on the compact pack, with strides N, 2N, ... The sum is exact in
+    two's complement in any order, and the j-th add sees the same plane
+    widths either way, so the result equals the shift path's bit for bit.
+    Each add charges the modelled access — the full M' * K_pad * N row the
+    array activates — not the words the emulation computes: the ledger is
+    identical to the shift path's."""
+    m, k, n = prod.shape
+    full = prod.n_words
+    acc = prod if prod.signed \
+        else prod.extend_to(prod.n_bits + 1).as_signed(True)
+    while k > 1 and (k // 2 * n) % 32 == 0:
+        k //= 2
+        lo, hi = (PlanePack(planes=half, n_bits=acc.n_bits, signed=True,
+                            shape=(m, k, n))
+                  for half in _fold_halves(acc.planes, m, k * n // 32))
+        acc = cur.execute(lo, hi, ("add",), charge_words=full)["add"]
+        cur.fold_steps += 1
+    stride = n
+    while k > 1:
+        k //= 2
+        shifted = acc.shift_elements(stride)
+        acc = cur.execute(acc, shifted, ("add",), charge_words=full)["add"]
+        cur.shift_steps += 1
+        stride *= 2
+    return acc
+
+
+#: a fold level slices the two halves of each row out of the plane stack
+#: while there are at most this many rows; past it (batched attention's
+#: slots x heads rows of short runs) one reshape splits every row at once.
+#: Both are plain copies. The bound keeps the level's op count, and so the
+#: TPU compile time, flat: hundreds of slices compile slowly, and so does
+#: a reshape of a long stack (a region's MLP has at most a few rows)
+_FOLD_SLICE_ROWS = 32
+
+
+def _fold_halves(planes: jax.Array, m: int,
+                 x: int) -> Tuple[jax.Array, jax.Array]:
+    """The lower and upper halves of each of the `m` rows of a plane stack
+    whose rows are 2 * x lane words each, as two [n, m * x] stacks."""
+    if m <= _FOLD_SLICE_ROWS:
+        return tuple(
+            jnp.concatenate([planes[:, (2 * r + i) * x:(2 * r + i + 1) * x]
+                             for r in range(m)], axis=1)
+            for i in (0, 1))
+    n = planes.shape[0]
+    rows = planes.reshape(n * m, 2 * x)
+    return tuple(rows[:, i * x:(i + 1) * x].reshape(n, m * x)
+                 for i in (0, 1))
+
+
+def _k0_slice(acc: PlanePack, shape: Tuple[int, ...]) -> PlanePack:
+    """The k = 0 slice of an [M', K, N] reduction result as a pack of
+    `shape` (M' * N words): flat(m, 0, n) = m * K * N + n. A gather where
+    K > 1; where K = 1 the pack already is that slice."""
+    m, k, n = acc.shape
+    if k == 1:
+        return dataclasses.replace(acc, shape=tuple(shape))
+    idx = np.arange(m)[:, None] * (k * n) + np.arange(n)[None, :]
+    return acc.take_words(idx.reshape(-1), shape)
+
+
+def _shift_reduce(cur: ScheduleCursor, prod: PlanePack,
+                  shape: Tuple[int, ...]) -> PlanePack:
+    """The matmul reduction as row-buffer shifts over the whole product
+    (banked and eager cursors), then the k = 0 gather."""
+    acc = _reduce_with(cur, prod, n_steps=planner._log2_ceil(prod.shape[1]))
+    return _k0_slice(acc, shape)
+
+
+def _matmul_reduce(cur: ScheduleCursor, prod: PlanePack,
+                   shape: Tuple[int, ...]) -> PlanePack:
+    """The log2(K_pad) stride-N tree reduction of a matmul's [M', K_pad, N]
+    product, returned as the [M', N] result pack of `shape`: a halving
+    fold on a cursor that `folds` (`_fold_reduce`), else row-buffer shifts
+    (`_shift_reduce`). Same result, same ledger."""
+    if cur.folds:
+        return _k0_slice(_fold_reduce(cur, prod), shape)
+    return _shift_reduce(cur, prod, shape)
 
 
 def _reduce_sum_body(cur: ScheduleCursor, a: PlanePack) -> PlanePack:
@@ -566,8 +704,9 @@ def _batched_matmul_with(cur: ScheduleCursor, a: jax.Array, b,
     multiply plus a log2(K_pad) stride-N tree reduction — is the 2-D
     `_matmul_with` dataflow verbatim with M' = B_flat * M. Correctness of
     the shared reduction follows from the 2-D argument: each (b, m) block
-    is a contiguous K_pad * N word segment whose k = 0 slice alone is
-    gathered at exit; cross-block garbage lands on discarded k > 0 slots.
+    is a contiguous K_pad * N word segment; the fold halves each block in
+    place, and the shifts' cross-block garbage lands on discarded k > 0
+    slots.
 
     With `b_pack` (a pinned `batched_matmul_rhs_pack`) the rhs side is
     RESIDENT: its per-batch expansion and entry pack are skipped and the
@@ -617,10 +756,7 @@ def _batched_matmul_with(cur: ScheduleCursor, a: jax.Array, b,
         cur.charge_resident(n_bits, pb.n_words)
 
     prod = _multiply_with(cur, pa, pb)
-    acc = _reduce_with(cur, prod, n_steps=planner._log2_ceil(k_pad))
-
-    idx = (np.arange(bf * m)[:, None] * (k_pad * n) + np.arange(n)[None, :])
-    return acc.take_words(idx.reshape(-1), bdims + (m, n))
+    return _matmul_reduce(cur, prod, bdims + (m, n))
 
 
 def _matmul_with(cur: ScheduleCursor, a: jax.Array, b,
@@ -628,9 +764,15 @@ def _matmul_with(cur: ScheduleCursor, a: jax.Array, b,
                  b_pack: Optional[PlanePack] = None) -> PlanePack:
     """The matmul dataflow over an open cursor: broadcast [M, K_pad, N]
     operand layout, ONE shift-and-add multiply, log2(K_pad) stride-N tree
-    reduction, result gathered to an [M, N] pack. Shared by the standalone
-    `matmul` wrapper and the lowering compiler's fused-region executor
-    (which passes a region cursor mid-schedule).
+    reduction to an [M, N] pack (`_matmul_reduce`). Shared by the
+    standalone `matmul` wrapper and the lowering compiler's fused-region
+    executor (which passes a region cursor mid-schedule).
+
+    On a traced unbanked cursor — every region program the model's call
+    sites lower — the reduction folds in halves, computing only the words
+    its result keeps, while the ledger charges each planned add at the
+    modelled full-row M * K_pad * N words. Banked and eager cursors shift
+    the whole row buffer and gather the k = 0 slice.
 
     With `b_pack` (a pinned `matmul_rhs_pack`) the rhs side is RESIDENT:
     its expansion and entry pack are skipped entirely — the streamed lhs
@@ -669,11 +811,7 @@ def _matmul_with(cur: ScheduleCursor, a: jax.Array, b,
         cur.charge_resident(n_bits, pb.n_words)
 
     prod = _multiply_with(cur, pa, pb)
-    acc = _reduce_with(cur, prod, n_steps=planner._log2_ceil(k_pad))
-
-    # k = 0 slice of each row: flat(m, 0, n) = m * K_pad * N + n
-    idx = (np.arange(m)[:, None] * (k_pad * n) + np.arange(n)[None, :])
-    return acc.take_words(idx.reshape(-1), (m, n))
+    return _matmul_reduce(cur, prod, (m, n))
 
 
 def matmul(a: jax.Array, b: Optional[jax.Array] = None, n_bits: int = 8,

@@ -577,7 +577,8 @@ def _print_cim_report(tag: str) -> None:
     cs = cache_stats()
     print(f"  schedule cache: {cs['hits']} hits / {cs['misses']} misses, "
           f"{cs['dispatches']} jitted dispatches, {cs['host_eqns']} host "
-          f"eqns; resident: "
+          f"eqns, {cs['reduce_fold_steps']} folded / "
+          f"{cs['reduce_shift_steps']} shifted reduction steps; resident: "
           f"{cs.get('resident_pins', 0)} pins / "
           f"{cs.get('resident_hits', 0)} hits / "
           f"{cs.get('resident_evictions', 0)} evictions, "
