@@ -5,7 +5,9 @@ it: `ArchConfig` from the registry, `Model`, `serve.build_engine`,
 `ServeEngine.run`. The benchmark makes the weights and the prompts from the
 seed, wraps the engine's prefill, decode and sampling calls from outside to
 time them and to end the window, and checks what they produced against the
-float32 reference in `bench/reference/`.
+float32 reference of the configuration's model family (`bench/reference/`,
+chosen by `model_io.family`), which also makes the weights and counts the
+FLOPs.
 
 The window of a closed-loop cell starts at the first step boundary after
 `warm_steps` decode steps; that of an open-loop cell when `ServeEngine.run`
@@ -30,7 +32,6 @@ import numpy as np
 
 from bench import model_io, record
 from bench.record import RequestLog, Run, Step
-from bench.reference import model as reference
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
@@ -229,7 +230,7 @@ def load_cell(workload: str):
     return bench, cell, model_io.load_config(cell["config"]), mix
 
 
-def _prompt_inputs(key, reqs, s: model_io.Sizes):
+def _prompt_inputs(key, reqs, s):
     """Each request's prompt from the seed: token ids, or frame embeddings
     (scaled like the engine's own) for a model fed by embeddings."""
     out = {}
@@ -285,11 +286,12 @@ def check_sample(logs: List[RequestLog], closed: bool, seed: int,
     return [longest] + [rest[i] for i in sorted(pick)]
 
 
-def served_gaps(params, s, logs, prompts, engine_key, slots,
+def served_gaps(fam, params, s, logs, prompts, engine_key, slots,
                 control: Optional[str] = None) -> List[np.ndarray]:
     """For every compared served token: how far the reference's logit of it
-    lies below the reference's best logit. With a `control` precision, the
-    token compared is the one the control puts first."""
+    lies below the reference's best logit, by the reference of family
+    module `fam`. With a `control` precision, the token compared is the one
+    the control puts first."""
     gaps = []
     for g in logs:
         n = len(g.token_ids)
@@ -305,12 +307,11 @@ def served_gaps(params, s, logs, prompts, engine_key, slots,
             inputs = np.concatenate([np.asarray(prompts[g.rid]["tokens"][0]),
                                      np.asarray(g.token_ids[:n - 1], np.int32)])
         rows = np.arange(p - 1, p - 1 + n)
-        ref = np.asarray(reference.logits_at(params, s, inputs, rows))
+        ref = np.asarray(fam.logits_at(params, s, inputs, rows))
         if control is None:
             picked = np.asarray(g.token_ids)
         else:
-            ctl = np.asarray(reference.logits_at(params, s, inputs, rows,
-                                                 control))
+            ctl = np.asarray(fam.logits_at(params, s, inputs, rows, control))
             picked = ctl.argmax(-1)
         gaps.append(ref.max(-1) - ref[np.arange(n), picked])
     return gaps
@@ -349,7 +350,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     closed = mix["loop"] == "closed"
     bits = (cim_bits or 8) if cim else 0
     cfg = model_io.arch_config(conf, cim_bits=bits, cim_resident=cim)
-    s = model_io.sizes_of(cfg)
+    fam = model_io.family(conf)
+    s = fam.sizes_of(cfg)
     device = jax.devices()[0]
     peaks = record.load_peaks(device.device_kind) \
         if device.platform == "tpu" else {}
@@ -358,7 +360,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         serve.reset_cim_state()
 
     key = model_io.seed_key(seed)
-    params = model_io.make_params(jax.random.fold_in(key, 0), s, unstacked=cim)
+    params = fam.make_params(jax.random.fold_in(key, 0), s, unstacked=cim)
     jax.block_until_ready(params)
     model = build(cfg)
     reqs_spec = serve_mix.generate(mix, seed, seconds)
@@ -432,7 +434,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
 
     limits = mix["check"]
     compared = check_sample(logs, closed, seed, limits.get("sample", 0))
-    per_request = served_gaps(params, s, compared, prompts, engine_key,
+    per_request = served_gaps(fam, params, s, compared, prompts, engine_key,
                               mix["slots"], control)
     gaps = np.concatenate(per_request) if per_request else np.zeros(0)
     max_gap = float(gaps.max()) if gaps.size else float("inf")
@@ -441,7 +443,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     setup_s = rec.window_t0 - t_start
     run = Run(workload=workload, sizes=s, peaks=peaks, setup_s=setup_s,
               window=(rec.window_t0, rec.window_t1), steps=rec.steps,
-              requests=logs)
+              requests=logs, flops=fam.decode_token_flops)
     if trace:
         from bench import trace as trace_mod
         run.trace = trace_mod.summarize(

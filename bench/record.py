@@ -9,7 +9,7 @@ from __future__ import annotations
 import dataclasses
 import json
 from pathlib import Path
-from typing import Dict, List, Optional
+from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
@@ -46,13 +46,15 @@ class RequestLog:
 @dataclasses.dataclass
 class Run:
     workload: str
-    sizes: object             # bench.model_io.Sizes
+    sizes: object             # the model family's `Sizes`
     peaks: Dict[str, float]
     setup_s: float
     window: tuple             # (t0, t1) host clock
     steps: List[Step]
     requests: List[RequestLog]
     trace: Optional[Dict] = None          # bench.trace.summarize(...)
+    # the model family's `decode_token_flops(sizes, context)`
+    flops: Optional[Callable[[object, int], float]] = None
 
     def window_steps(self, kind: str, traced: Optional[bool] = None):
         t0, t1 = self.window
@@ -77,25 +79,15 @@ def p95(values) -> Optional[float]:
     return float(np.percentile(values, 95)) if len(values) else None
 
 
-def decode_token_flops(s, context: int) -> float:
-    """Model FLOPs of one decoded token at `context` cached positions: two
-    per weight of every matrix product, and the attention's QK and AV."""
-    per_layer = (s.d_model * s.n_heads * s.head_dim
-                 + 2 * s.d_model * s.n_kv_heads * s.head_dim
-                 + s.n_heads * s.head_dim * s.d_model
-                 + (3 if s.gated else 2) * s.d_model * s.d_ff)
-    weights = s.n_layers * per_layer + s.d_model * s.vocab
-    return 2.0 * weights + 4.0 * s.n_layers * s.n_heads * s.head_dim * context
-
-
 def token_flops(run: Run, steps) -> float:
-    """Model FLOPs of the tokens that the decode `steps` produced."""
+    """Model FLOPs of the tokens that the decode `steps` produced, by the
+    run's own FLOP count (`Run.flops`)."""
     want = {s.index for s in steps}
     total = 0.0
     for r in run.requests:
         for j, st in enumerate(r.token_steps):
             if st in want:
-                total += decode_token_flops(run.sizes, r.prompt_len + j)
+                total += run.flops(run.sizes, r.prompt_len + j)
     return total
 
 

@@ -1,11 +1,18 @@
-"""Plain float32 reference of the benchmark's decoder models.
+"""The dense decoder family: its sizes, weights, FLOP count and plain
+float32 reference.
 
-A pre-norm decoder written in straightforward `jax.numpy`, one layer per
-jitted call, with no cache and no batching: RMSNorm, grouped-query attention
-with RoPE over adjacent pairs, causal softmax, and a SwiGLU or GELU MLP,
-then a final RMSNorm and an untied LM head. Every matrix product runs at
-`jax.default_matmul_precision("highest")`. It imports nothing of the system
-under test; it reads the benchmark's weights (`bench.model_io`).
+A configuration with no `"reference"` key is of this family
+(`bench.model_io.family`). The family's weights are made here, from the
+seed, on the device, in one jitted call, in bfloat16 (the type they are
+served in), in the program's own parameter layout; the reference reads the
+same arrays through `layer_weights`.
+
+The reference is a pre-norm decoder written in straightforward `jax.numpy`,
+one layer per jitted call, with no cache and no batching: RMSNorm,
+grouped-query attention with RoPE over adjacent pairs, causal softmax, and
+a SwiGLU or GELU MLP, then a final RMSNorm and an untied LM head. Every
+matrix product runs at `jax.default_matmul_precision("highest")`. It imports
+nothing of the system under test.
 
 Departures from the published models follow the system's own equations and
 are listed in each configuration file under `departures`.
@@ -17,6 +24,7 @@ magnitude to 448) or to symmetric integers ("int4", "int8").
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Optional
 
@@ -24,9 +32,115 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.model_io import Sizes, layer_weights
-
 BLOCK = 256          # sequences are padded to a multiple of this
+
+
+@dataclasses.dataclass(frozen=True)
+class Sizes:
+    """The shapes the benchmark needs, independent of the program."""
+
+    n_layers: int
+    d_model: int
+    n_heads: int
+    n_kv_heads: int
+    head_dim: int
+    d_ff: int
+    vocab: int
+    vocab_padded: int
+    gated: bool          # SwiGLU (three matrices) or plain GELU (two)
+    embed_stub: bool     # inputs are frame embeddings, not token ids
+    norm_eps: float
+    rope_theta: float
+
+
+def sizes_of(cfg) -> Sizes:
+    if cfg.gating not in ("swiglu", "none"):
+        raise ValueError(f"gating {cfg.gating!r} has no reference here")
+    return Sizes(n_layers=cfg.n_layers, d_model=cfg.d_model,
+                 n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
+                 head_dim=cfg.head_dim, d_ff=cfg.d_ff, vocab=cfg.vocab_size,
+                 vocab_padded=cfg.vocab_padded, gated=cfg.gating == "swiglu",
+                 embed_stub=cfg.embed_stub, norm_eps=cfg.norm_eps,
+                 rope_theta=cfg.rope_theta)
+
+
+def _make_layer(key, s: Sizes, dtype):
+    d, h, hkv, hd, f = s.d_model, s.n_heads, s.n_kv_heads, s.head_dim, s.d_ff
+    ks = jax.random.split(key, 9)
+
+    def dense(k, shape, fan_in):
+        return (jax.random.normal(k, shape, jnp.float32)
+                * fan_in ** -0.5).astype(dtype)
+
+    def scale(k):
+        return (1.0 + 0.1 * jax.random.normal(k, (d,), jnp.float32)
+                ).astype(dtype)
+
+    mlp = {"w_in": dense(ks[0], (d, f), d), "w_out": dense(ks[1], (f, d), f)}
+    if s.gated:
+        mlp["w_gate"] = dense(ks[2], (d, f), d)
+    return {
+        "ln1": {"scale": scale(ks[3])},
+        "attn": {"wq": dense(ks[4], (d, h, hd), d),
+                 "wk": dense(ks[5], (d, hkv, hd), d),
+                 "wv": dense(ks[6], (d, hkv, hd), d),
+                 "wo": dense(ks[7], (h, hd, d), h * hd)},
+        "ln2": {"scale": scale(ks[8])},
+        "mlp": mlp,
+    }
+
+
+def make_params(key, s: Sizes, unstacked: bool, dtype=jnp.bfloat16):
+    """Weights in the program's parameter layout, made in one jitted call.
+
+    `unstacked` gives the layers as `group_layers` (one tuple per layer, how
+    the lowered path serves them); otherwise they are stacked under `groups`
+    for the scanned path. Both hold the same values for one key."""
+
+    def build(key):
+        k_emb, k_layers, k_norm, k_head = jax.random.split(key, 4)
+        layers = [_make_layer(jax.random.fold_in(k_layers, i), s, dtype)
+                  for i in range(s.n_layers)]
+        p = {"first_dense": [], "rem": [],
+             "final_norm": {"scale": (1.0 + 0.1 * jax.random.normal(
+                 k_norm, (s.d_model,), jnp.float32)).astype(dtype)},
+             "lm_head": {"w": (jax.random.normal(
+                 k_head, (s.d_model, s.vocab_padded), jnp.float32)
+                 * s.d_model ** -0.5).astype(dtype)}}
+        if not s.embed_stub:
+            p["embed"] = {"table": jax.random.normal(
+                k_emb, (s.vocab_padded, s.d_model), jnp.float32
+            ).astype(dtype)}
+        if unstacked:
+            p["group_layers"] = [(layer,) for layer in layers]
+        else:
+            p["groups"] = (jax.tree.map(lambda *xs: jnp.stack(xs), *layers),)
+        return p
+
+    return jax.jit(build)(key)
+
+
+def layer_weights(params, i: int) -> dict:
+    """Layer `i`'s weights as a flat dict, from either layout."""
+    if "group_layers" in params:
+        lp = params["group_layers"][i][0]
+    else:
+        lp = jax.tree.map(lambda a: a[i], params["groups"][0])
+    out = {"ln1": lp["ln1"]["scale"], "ln2": lp["ln2"]["scale"]}
+    out.update(lp["attn"])
+    out.update(lp["mlp"])
+    return out
+
+
+def decode_token_flops(s: Sizes, context: int) -> float:
+    """Model FLOPs of one decoded token at `context` cached positions: two
+    per weight of every matrix product, and the attention's QK and AV."""
+    per_layer = (s.d_model * s.n_heads * s.head_dim
+                 + 2 * s.d_model * s.n_kv_heads * s.head_dim
+                 + s.n_heads * s.head_dim * s.d_model
+                 + (3 if s.gated else 2) * s.d_model * s.d_ff)
+    weights = s.n_layers * per_layer + s.d_model * s.vocab
+    return 2.0 * weights + 4.0 * s.n_layers * s.n_heads * s.head_dim * context
 
 
 def _quant(x, control: Optional[str]):

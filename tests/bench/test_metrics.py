@@ -1,10 +1,11 @@
 """The metric readers on a small hand-made run."""
 import pytest
 
-from bench import cell, model_io, record
+from bench import cell
+from bench.reference import model as dense
 from bench.record import RequestLog, Run, Step
 
-S = model_io.Sizes(1, 4, 1, 1, 4, 8, 10, 256, False, True, 1e-5, 1e4)
+S = dense.Sizes(1, 4, 1, 1, 4, 8, 10, 256, False, True, 1e-5, 1e4)
 PEAKS = {"bf16_flops_per_s": 1e6, "hbm_bytes_per_s": 1e3}
 
 
@@ -18,7 +19,8 @@ def run(trace=None):
                        token_times=[11.0, 12.0, 14.0],
                        token_steps=[-1, 0, 1], token_ids=[1, 2, 3]),
             RequestLog(1, 3, 3, 15.0)]
-    return Run("w", S, PEAKS, 42.0, (10.0, 20.0), steps, reqs, trace)
+    return Run("w", S, PEAKS, 42.0, (10.0, 20.0), steps, reqs, trace,
+               flops=dense.decode_token_flops)
 
 
 def value(name, r):
@@ -39,7 +41,7 @@ def test_step_readers_leave_traced_steps_out():
     assert value("decode_step_ms.cim", r) == pytest.approx(500.0)
     assert value("prefill_ms_per_ktok.chat", r) == pytest.approx(1000 / 0.003)
     assert value("mfu.cim", r) == pytest.approx(
-        100 * (record.decode_token_flops(S, 4) + record.decode_token_flops(S, 5))
+        100 * (dense.decode_token_flops(S, 4) + dense.decode_token_flops(S, 5))
         / (10 * 1e6))
 
 

@@ -20,10 +20,10 @@ def test_prefill_then_cached_decode_matches_full_forward(conf):
 
     cfg = dataclasses.replace(model_io.arch_config(conf), dtype="float32",
                               param_dtype="float32")
-    s = model_io.sizes_of(cfg)
+    s = reference.sizes_of(cfg)
     model = build(cfg)
-    params = model_io.make_params(jax.random.PRNGKey(3), s, unstacked=False,
-                                  dtype=jnp.float32)
+    params = reference.make_params(jax.random.PRNGKey(3), s, unstacked=False,
+                                   dtype=jnp.float32)
     p, n, max_len = 5, 6, 16
     key = jax.random.PRNGKey(4)
     if s.embed_stub:
@@ -49,8 +49,8 @@ def test_prefill_then_cached_decode_matches_full_forward(conf):
 
 def test_control_precisions_depart_from_the_reference():
     conf = tiny.TINY
-    s = model_io.sizes_of(model_io.arch_config(conf))
-    params = model_io.make_params(jax.random.PRNGKey(5), s, unstacked=True)
+    s = reference.sizes_of(model_io.arch_config(conf))
+    params = reference.make_params(jax.random.PRNGKey(5), s, unstacked=True)
     seq = np.asarray(jax.random.randint(jax.random.PRNGKey(6), (40,), 0,
                                         s.vocab))
     rows = np.arange(40)
