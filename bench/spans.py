@@ -21,10 +21,24 @@ and sums up what spans from outside the program cannot tell:
                  counter as each `serve.decode` span carries it
   scopes         device time by program, innermost `cim.*` named scope and
                  op, from the profile's trace-viewer file
+  span_args      by span name, the program's spans in the window: their
+                 `count` and, under `sum`, the total of each numeric
+                 argument they carry (a string such as `cim.call`'s `fn`
+                 is not summed)
+  region_s_by_fn the device time of the region programs, `bench/trace.py`'s
+                 `region_s` over the same modules and the whole trace, by
+                 the lowered function `<fn>` of each program's name
+                 `jit_cim_<fn>_r<k>` (`?` for a name of another form); a
+                 program shared by identical regions keeps its first name,
+                 so its time goes to that name's function
 
 The window is `bench/trace.py`'s: from the first to the last `bench.*`
 span. A span open when the profiler started or stopped is not in the
 profile: the first traced decode step has no `serve.decode` span.
+
+A new mechanism's spans and counters need no edit here: its per-layer
+metric is a new reader `bench/metrics/<name>.py` over `span_args` and
+`region_s_by_fn`.
 
 The readers in `bench/metrics/` get a run's summary from `of_run`: the
 profile in the run's own trace directory, `.bench_out/trace/<workload>-
@@ -40,6 +54,7 @@ import dataclasses
 import gzip
 import heapq
 import json
+import re
 import sys
 from pathlib import Path
 from typing import Dict, List, Optional, Tuple
@@ -51,6 +66,8 @@ PROGRAM = ("serve.", "model.", "cim.")
 OUTSIDE = "outside spans"
 #: the engine's loop outside the model calls
 LOOP = ("serve.admit", "serve.insert", "serve.emit", "serve.wait")
+#: a region program's name (`repro.cim.lower`): the lowered function, the region
+REGION = re.compile(r"jit_cim_(.+)_r\d+")
 
 Span = Tuple[str, float, float, Dict]
 
@@ -210,7 +227,32 @@ def summarize(st: SpanTrace) -> Dict:
         "serve_decode_ms": 1e3 * sum(e - s for _, s, e, _ in decodes)
         / 1e9 / len(decodes) if decodes else None,
         "scopes": st.scopes,
+        "span_args": span_args(inwin),
+        "region_s_by_fn": region_split(t),
     }
+
+
+def span_args(spans) -> Dict[str, Dict]:
+    """Each span name's count and the sums of its numeric arguments."""
+    out: Dict[str, Dict] = {}
+    for name, _, _, args in spans:
+        entry = out.setdefault(name, {"count": 0, "sum": {}})
+        entry["count"] += 1
+        for k, v in args.items():
+            if isinstance(v, (int, float)) and not isinstance(v, bool):
+                entry["sum"][k] = entry["sum"].get(k, 0) + v
+    return out
+
+
+def region_split(t: trace_mod.Trace) -> Dict[str, float]:
+    """Region programs' device time (s) by lowered function, most first."""
+    out: Dict[str, float] = {}
+    for (name, s, e), reg in zip(t.modules, trace_mod.region_modules(t)):
+        if reg:
+            m = REGION.fullmatch(trace_mod.base_name(name))
+            fn = m.group(1) if m else "?"
+            out[fn] = out.get(fn, 0.0) + e - s
+    return {k: v / 1e9 for k, v in sorted(out.items(), key=lambda kv: -kv[1])}
 
 
 def scope_split(viewer_json, top: int = 16) -> List[list]:

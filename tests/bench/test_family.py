@@ -2,7 +2,9 @@
 added as a new file alone is the one a run uses for its weights, its
 reference and its FLOP count; nested blocks of the program's `ArchConfig`."""
 import dataclasses
+import json
 import time
+from pathlib import Path
 
 import pytest
 
@@ -30,14 +32,47 @@ def decode_token_flops(s, context):
 '''
 
 
+EXPORTS = ("Sizes", "sizes_of", "make_params", "logits_at",
+           "decode_token_flops")
+
+
+def check_named_family(conf):
+    """`model_io.family(conf)` is the module loaded from
+    `bench/reference/<conf["reference"]>.py` (`model.py` where the key is
+    absent), and exports every name the harness reads."""
+    path = model_io.BENCH / "reference" / f"{conf.get('reference', 'model')}.py"
+    fam = model_io.family(conf)
+    assert Path(fam.__file__).resolve() == path.resolve()
+    for name in EXPORTS:
+        assert callable(getattr(fam, name)), name
+    return fam
+
+
 @pytest.mark.parametrize("name", sorted(
     p.stem for p in (model_io.BENCH / "configs").glob("*.json")) + ["tiny"])
-def test_no_reference_key_is_the_dense_family(name):
+def test_each_configuration_gets_the_family_it_names(name):
     conf = tiny.TINY if name == "tiny" else model_io.load_config(name)
-    assert "reference" not in conf
-    fam = model_io.family(conf)
-    assert fam is dense
-    assert fam.__file__ == str(model_io.BENCH / "reference" / "model.py")
+    fam = check_named_family(conf)
+    if "reference" not in conf:
+        assert fam is dense
+
+
+def test_configuration_naming_a_new_family_as_new_files(monkeypatch,
+                                                        tmp_path):
+    """A configuration file that carries `"reference"`, beside the family
+    it names, both new files: `load_config` finds the one and `family`
+    loads the other."""
+    (tmp_path / "configs").mkdir()
+    (tmp_path / "reference").mkdir()
+    (tmp_path / "reference" / "doubled.py").write_text(DOUBLED)
+    conf = dict(model_io.load_config("granite-3-8b-s8"), reference="doubled")
+    (tmp_path / "configs" / "probe.json").write_text(json.dumps(conf))
+    monkeypatch.setattr(model_io, "BENCH", tmp_path)
+    monkeypatch.setattr(model_io, "FAMILIES", tmp_path / "reference")
+    loaded = model_io.load_config("probe")
+    assert loaded == conf
+    fam = check_named_family(loaded)
+    assert fam is not dense
 
 
 def test_unknown_family_is_an_error():

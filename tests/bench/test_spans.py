@@ -139,9 +139,35 @@ def test_summary_of_a_synthetic_trace(tmp_path):
         "serve.wait": 0.0})
     assert s["host_eqns"] == 14 and s["cim_spans"] == 6
     assert s["host_reads_per_step"] == 2.0
+    # the window holds the first serve.decode, not the second; `fn` is a
+    # string and is not summed
+    assert s["span_args"]["serve.decode"] == {
+        "count": 1, "sum": {"step": 1, "host_reads": 2}}
+    assert s["span_args"]["cim.call"] == {"count": 2, "sum": {}}
+    assert s["span_args"]["cim.host"] == {"count": 2, "sum": {"eqns": 14}}
+    assert s["region_s_by_fn"] == pytest.approx({"mlp": 4e-3})
     # from 6 ms: 2 ms of [12, 14] in serve.decode and 0.5 ms of [17, 17.5]
     # in serve.emit, of 2.5 ms busy
     assert s["clock_check"] == pytest.approx(100.0)
+
+
+def test_region_split_by_lowered_function():
+    """A region program goes under the `<fn>` of `jit_cim_<fn>_r<k>`, or
+    `?`; a program without a fused-kernel call is no region."""
+    modules = [("jit_cim_attn_bmm_r3(5)", 0.0, 2 * MS),
+               ("jit_cim_mlp_r0(1)", 3 * MS, 4 * MS),
+               ("jit_add(2)", 5 * MS, 6 * MS),
+               ("jit_step(3)", 7 * MS, 10 * MS),
+               ("jit_cim_mlp_r2(1)", 11 * MS, 12.5 * MS)]
+    ops = [("%fused_planes_op.1 = custom-call()", s + 0.1 * MS, e)
+           for name, s, e in modules if name != "jit_add(2)"]
+    t = trace.Trace(modules, sorted(ops + [("%add.1 = add()", 5 * MS,
+                                            6 * MS)], key=lambda o: o[1]),
+                    [])
+    got = spans.region_split(t)
+    assert got == pytest.approx({"attn_bmm": 2e-3, "mlp": 2.5e-3,
+                                 "?": 3e-3})
+    assert list(got) == ["?", "mlp", "attn_bmm"]
 
 
 def test_readers(monkeypatch):
@@ -246,6 +272,111 @@ def test_recorded_serve_trace():
             "jit_cim_sdpa_r1"}
     assert sum(regions) == 10
     assert "jit_cim_mlp_r0:cim.kernel/fused_planes_op" in dict(st.scopes)
+
+
+#: what `summarize` gave for the stored profile before it had `span_args`
+#: and `region_s_by_fn`: those keys keep these values
+STORED_SUMMARY = {
+    "window_s": 0.23160984,
+    "program_spans": 53,
+    "idle_by_span": [["cim.host", 0.110136661],
+                     ["model.qkv", 0.052317465],
+                     ["model.kv_write", 0.028128054],
+                     ["model.norm", 0.013308962],
+                     ["model.cache_slice", 0.007260162],
+                     ["model.attn", 0.004482041],
+                     ["cim.region", 0.004375487],
+                     ["model.head", 0.001967946],
+                     ["model.cache_stack", 0.001946648],
+                     ["cim.call", 0.001903801],
+                     ["model.out_proj", 0.001819715],
+                     ["bench.decode", 0.001232784],
+                     ["model.cast", 0.001060042],
+                     ["model.mlp", 0.000564369],
+                     ["model.embed", 0.00033827],
+                     ["bench.sample", 0.00019227],
+                     ["model.layer", 9.469e-05],
+                     ["outside spans", 6.579e-05],
+                     ["serve.sample", 3.16e-06]],
+    "loop_idle_s": {"serve.admit": 0.0, "serve.insert": 0.0,
+                    "serve.emit": 0.0, "serve.wait": 0.0},
+    "clock_check": 100.0,
+    "cim_spans": 28,
+    "host_eqns": 288,
+    "host_reads_per_step": None,
+    "serve_decode_ms": None,
+    "scopes": [
+        ["jit_convert_element_type:convert_element_type",
+         4.5784921999999996e-05],
+        ["jit_broadcast_in_dim:copy", 3.0734688000000016e-05],
+        ["jit_multiply:broadcast_multiply_fusion", 1.9512344e-05],
+        ["jit_cim_mlp_r0:cim.kernel/fused_planes_op", 1.2753827999999996e-05],
+        ["jit_abs:abs", 1.2720156000000002e-05],
+        ["jit_squeeze:copy", 1.0808515999999999e-05],
+        ["jit_cim_mlp_r0:cim.multiply/slice-done", 9.80125e-06],
+        ["jit_cim_mlp_r0:copy-done", 9.737187999999998e-06],
+        ["jit_reshape:copy", 9.64586e-06],
+        ["jit_round:round", 8.335782e-06],
+        ["jit_div:broadcast_divide_fusion", 7.793594e-06],
+        ["jit_max:broadcast_maximum_fusion", 7.630078e-06],
+        ["jit_reduce_max:copy-done", 7.497185999999999e-06],
+        ["jit_cim_sdpa_r1:cim.kernel/fused_planes_op", 6.8539839999999995e-06],
+        ["jit_cim_mlp_r2:cim.kernel/fused_planes_op", 6.852968e-06],
+        ["jit_dynamic_slice:copy", 6.8278900000000005e-06]],
+}
+
+
+def test_recorded_serve_trace_counters_and_region_split():
+    """The stored profile's spans' counters and its region time by lowered
+    function, beside every key the summary had, value for value."""
+    st = spans.SpanTrace.from_json(DATA / "serve_cim_spans.json.gz")
+    s = spans.summarize(st)
+    assert set(s) == set(STORED_SUMMARY) | {"span_args", "region_s_by_fn"}
+    for key, value in STORED_SUMMARY.items():
+        assert s[key] == value, key
+    by_fn = s["region_s_by_fn"]
+    assert sum(by_fn.values()) == pytest.approx(
+        trace.summarize(st.trace)["region_s"], rel=1e-9, abs=0)
+    assert set(by_fn) == {"mlp", "sdpa"}
+    args = s["span_args"]
+    assert args["cim.host"]["sum"]["eqns"] == s["host_eqns"] == 288
+    lo = min(h[1] for h in st.trace.host)
+    hi = max(h[2] for h in st.trace.host)
+    assert args["cim.region"]["count"] == sum(
+        1 for n, a, b, _ in st.spans
+        if n == "cim.region" and b > lo and a < hi) == 10
+    assert "serve.decode" not in args
+    assert sum(a["count"] for a in args.values()) == s["program_spans"]
+    assert sum(a["count"] for n, a in args.items()
+               if n.startswith("cim.")) == s["cim_spans"]
+
+
+HOST_EQNS_READER = '''"""Eqns bound by the lowering's host islands in the traced window, from
+the `cim.host` spans' summed `eqns`."""
+from bench import spans
+
+
+def read(run):
+    s = spans.of_run(run)
+    host = (s or {}).get("span_args", {}).get("cim.host")
+    return host["sum"].get("eqns") if host else None
+'''
+
+
+def test_reader_added_as_a_file_reads_span_args(monkeypatch, tmp_path):
+    """A per-layer metric of the program's counters is a new reader file
+    over `span_args`: no edit to `bench/spans.py`."""
+    (tmp_path / "metrics").mkdir()
+    (tmp_path / "metrics" / "island_eqns.py").write_text(HOST_EQNS_READER)
+    monkeypatch.setattr(cell, "BENCH", tmp_path)
+    summary = spans.summarize(
+        spans.SpanTrace.from_json(DATA / "serve_cim_spans.json.gz"))
+    run = Run("w", None, {}, 1.0, (0.0, 1.0), [], [])
+    read = cell.load_reader("island_eqns")
+    monkeypatch.setattr(spans, "of_run", lambda r: summary)
+    assert read(run) == summary["host_eqns"] == 288
+    monkeypatch.setattr(spans, "of_run", lambda r: None)
+    assert read(run) is None
 
 
 def test_scope_split(tmp_path):
